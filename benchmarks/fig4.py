@@ -70,13 +70,11 @@ def run(*, dataset="cora", epochs=60, strategies=("sequential", "greedy", "halo"
     # under every schedule — fill-drain runs the fused scan, 1F1B and
     # interleaved the scheduled executor. Accuracy must sit on top of the
     # host fill-drain row for all of them (schedule- AND engine-invariance).
-    for schedule, pipe_devices in (
-        ("fill_drain", None), ("1f1b", None), ("interleaved", 2),
-        ("zb-h1", None), ("zb-v", 2),
-    ):
+    # (interleaved and zb-v take their default 2-device ring)
+    for schedule in ("fill_drain", "1f1b", "interleaved", "zb-h1", "zb-v"):
         r = run_gnn(_args(
             dataset, epochs, strategy="halo", engine="compiled",
-            stages=4, chunks=4, schedule=schedule, pipe_devices=pipe_devices,
+            stages=4, chunks=4, schedule=schedule,
         ))
         emit(
             f"fig4/{dataset}/halo_chunks4_compiled_{schedule}",

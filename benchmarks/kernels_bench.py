@@ -22,9 +22,9 @@ import jax.numpy as jnp
 
 from benchmarks.common import emit, timed
 from repro.graphs import degree_bucketed_layout, load_dataset
-from repro.kernels.gat_edge.kernel import gat_aggregate_kernel
+from repro.kernels.gat_edge.kernel import bucket_gat_kernel
 from repro.kernels.gat_edge.ops import bucketed_gat_aggregate, gat_aggregate
-from repro.kernels.gat_edge.ref import gat_aggregate_ref
+from repro.kernels.gat_edge.ref import bucket_gat_ref
 from repro.kernels.spmm.kernel import padded_spmm_kernel
 from repro.kernels.spmm.ops import bucketed_spmm, padded_spmm
 from repro.kernels.spmm.ref import padded_spmm_ref
@@ -120,12 +120,14 @@ def run(*, json_path=None):
     k = jax.random.PRNGKey(0)
     # GAT edge (cora-scale)
     h_, n, d, f = 8, 2708, 14, 8
-    nbr_hw = jax.random.normal(k, (h_, n, d, f))
+    hw_heads = jax.random.normal(k, (h_, n, f))
+    nbr_g = jax.random.randint(jax.random.fold_in(k, 3), (n, d), 0, n)
     s_self = jax.random.normal(jax.random.fold_in(k, 1), (h_, n))
     s_nbr = jax.random.normal(jax.random.fold_in(k, 2), (h_, n, d))
     mask = jnp.ones((n, d), bool)
-    t_ref = timed(jax.jit(gat_aggregate_ref), nbr_hw, s_self, s_nbr, mask)
-    t_ker = timed(lambda *a: gat_aggregate_kernel(*a), nbr_hw, s_self, s_nbr, mask)
+    args = (hw_heads, nbr_g, s_self, s_nbr, mask)
+    t_ref = timed(jax.jit(bucket_gat_ref), *args)
+    t_ker = timed(lambda *a: bucket_gat_kernel(*a), *args)
     emit("kernels/gat_edge/ref", t_ref, f"n={n};d={d};h={h_}")
     emit("kernels/gat_edge/pallas_interpret", t_ker, "same shape")
 

@@ -29,6 +29,9 @@ def main() -> None:
                          "columns (pallas runs the fused kernel in interpret "
                          "mode on CPU)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     epochs = 300 if args.full else (15 if args.fast else 60)
     dataset = "pubmed" if args.full else args.dataset

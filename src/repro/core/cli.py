@@ -153,7 +153,18 @@ class PipelineCLIConfig:
 
     def gpipe_config(self, balance=None) -> GPipeConfig:
         """The assembled engine config. ``balance`` defaults to the uniform
-        layer-count split; the profiled partitioner passes its own."""
+        layer-count split; the profiled partitioner passes its own. An
+        explicit ``--pipe-devices`` beyond the host's devices is an error
+        for the compiled engine, not a quiet run on the one-device lane
+        substrate."""
+        if self.engine == "compiled" and self.pipe_devices is not None:
+            import jax
+
+            if jax.device_count() < self.pipe_devices:
+                raise ValueError(
+                    f"--pipe-devices {self.pipe_devices} asks for more devices "
+                    f"than this host has ({jax.device_count()})"
+                )
         return GPipeConfig(
             balance=tuple(balance if balance is not None else self.uniform_balance()),
             chunks=self.chunks,

@@ -187,12 +187,16 @@ def profile_layer_costs(
 def profile_fingerprint(model, params, graph, backend: str = "padded") -> str:
     """The cache key a profile is stored under: a digest of the model's
     layer stack (names + every param leaf's shape/dtype), the chunk shape
-    the engines dispatch per tick, and the aggregation backend. Two runs
-    measuring the same (model, chunk shape, backend) triple re-measure the
-    same jitted programs, so their costs are interchangeable — anything
-    else (different widths, padding, backend lowering) is a different
-    key."""
+    the engines dispatch per tick, the aggregation backend and the device
+    that measures (platform and kind). Two runs measuring the same (model,
+    chunk shape, backend) on the same kind of device re-measure the same
+    jitted programs, so their costs are interchangeable — anything else
+    (different widths, padding, backend lowering, a CPU profile read on a
+    TPU) is a different key."""
+    device = jax.devices()[0]
     spec = {
+        "platform": device.platform,
+        "device_kind": device.device_kind,
         "layers": [layer.name for layer in model.layers],
         "params": [
             [(list(a.shape), str(a.dtype)) for a in jax.tree_util.tree_leaves(p)]

@@ -175,23 +175,16 @@ def capture_overlap_report(step_fn: Callable[[], None], *, trace_dir: str | None
     the result (tracing a compile would attribute tracing-time Python to
     the step). The profiler's output stays on disk at ``trace_dir``
     (a fresh temp dir by default) — CI uploads it next to the JSON report.
-    If the profiler is unavailable the report carries an ``error`` field
-    and zeroed metrics instead of raising: the overlap gate compares step
-    times either way."""
+    A profiler that fails raises: zeroed metrics would read as a measured
+    absence of overlap."""
     import jax
 
     out_dir = trace_dir or tempfile.mkdtemp(prefix="overlap_trace_")
+    jax.profiler.start_trace(out_dir)
     try:
-        jax.profiler.start_trace(out_dir)
-        try:
-            step_fn()
-        finally:
-            jax.profiler.stop_trace()
-    except Exception as exc:  # profiler missing/busy: degrade, don't fail the run
-        report = overlap_from_events([])
-        report["error"] = f"{type(exc).__name__}: {exc}"
-        report["trace_dir"] = out_dir
-        return report
+        step_fn()
+    finally:
+        jax.profiler.stop_trace()
     report = overlap_from_events(load_trace_events(out_dir))
     report["trace_dir"] = out_dir
     return report

@@ -55,7 +55,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.core import compat
+from repro.core.vma import match_vma
 from repro.core.microbatch import MicroBatchPlan
 from repro.graphs.data import BucketedGraphBatch
 from repro.graphs.partition import bucketize_stacked
@@ -131,6 +131,13 @@ class GPipeConfig:
     def num_stages(self) -> int:
         """Pipeline stages (= entries in ``balance``)."""
         return len(self.balance)
+
+
+def _varying(branches, axes: tuple[str, ...]):
+    """``lax.switch`` branches whose outputs all vary over ``axes``: an idle
+    branch's zeros are unvarying while a compute branch's outputs vary over
+    the ring, and the switch requires one output type."""
+    return [lambda operand, b=b: match_vma(b(operand), extra=axes) for b in branches]
 
 
 @jax.jit
@@ -774,6 +781,11 @@ class CompiledGNNPipeline(PipelineEngine):
         model, bounds, remat = self.model, self._bounds, self.config.remat
 
         def local_loss(params, travel, graph, labels, m, count, rng):
+            # vary the params over the ring HERE: the transpose of this cast
+            # is the one psum of the stage gradients, outside the scan. Left
+            # implicit, it lands inside the stage switch, where only the
+            # devices taking a branch would issue it
+            params = match_vma(params, extra=("stage",))
             stage_fn = make_gnn_stage(
                 model, params, bounds, widths, graph, rng, stage_axis="stage", train=True
             )
@@ -823,7 +835,7 @@ class CompiledGNNPipeline(PipelineEngine):
         S = self.config.num_stages
         if jax.device_count() >= S:
             mesh = jax.sharding.Mesh(self._mesh_devices(S), ("stage",))
-            loss_fn = compat.shard_map(
+            loss_fn = jax.shard_map(
                 self._make_local_loss(widths), mesh=mesh,
                 in_specs=(P(),) * 7, out_specs=P(),
             )
@@ -846,7 +858,8 @@ class CompiledGNNPipeline(PipelineEngine):
         return jax.jit(step)
 
     def _make_work_fn(
-        self, widths: list[int], params, graph, labels, m, rng, *, phases, chunk_offset=0
+        self, widths: list[int], params, graph, labels, m, rng, *, phases,
+        chunk_offset=0, vary: tuple[str, ...] = (),
     ):
         """The per-tick work dispatcher for ``spmd_pipeline_scheduled``: one
         ``lax.switch`` over 1 + 4·S branches (idle, then fwd / fused bwd /
@@ -862,9 +875,16 @@ class CompiledGNNPipeline(PipelineEngine):
         loss trajectory matches chunk for chunk. Split B/W branches come
         from ``make_gnn_stage_slices_bw``: B emits the upstream cotangent
         plus the (input, cotangent) residual; W re-materializes from the
-        residual and emits the deferred weight grad."""
+        residual and emits the deferred weight grad.
+
+        ``vary`` names the mesh axes of the enclosing ``shard_map``. The
+        params are cast to vary over them before any branch closes over
+        them, so each ``jax.vjp`` yields this device's own gradients (no
+        collective inside a branch), and every branch's outputs are cast
+        to the same axes so the switch sees one output type."""
         S = self.config.num_stages
         model = self.model
+        params = match_vma(params, extra=vary)
         slices = make_gnn_stage_slices(
             model, self._bounds, widths, graph, rng, train=True,
             chunk_offset=chunk_offset,
@@ -952,7 +972,7 @@ class CompiledGNNPipeline(PipelineEngine):
             # idle -> 0, fwd(s) -> 1 + s, bwd(s) -> 1 + S + s,
             # bwd_b(s) -> 1 + 2S + s, bwd_w(s) -> 1 + 3S + s
             index = jnp.where(phase == 0, 0, (phase - 1) * S + stage + 1)
-            return lax.switch(index, branches, (chunk, h_in, ct, w_res))
+            return lax.switch(index, _varying(branches, vary), (chunk, h_in, ct, w_res))
 
         return work_fn
 
@@ -993,9 +1013,8 @@ class CompiledGNNPipeline(PipelineEngine):
         canonical GLOBAL chunk order — bit-identical to one replica (each
         (layer, chunk) gradient lives on exactly one replica and stage; see
         ``spmd_pipeline_scheduled``). Dropout keys stay global through the
-        ``chunk_offset`` fold in the stage slices. With fewer than dp·ring
-        devices the step falls back to the single-replica substrate over
-        all chunks — the identical update, just not data-distributed."""
+        ``chunk_offset`` fold in the stage slices. A host with fewer than
+        dp·ring devices is an error: the mesh would not exist."""
         S = self.config.num_stages
         dp = self.config.data_parallel
         if dp > 1 and chunks % dp:
@@ -1008,15 +1027,18 @@ class CompiledGNNPipeline(PipelineEngine):
             skip_chunks if dp == 1 else (),
         )
         D = lowered.num_devices
-        dp_active = dp > 1 and jax.device_count() >= dp * D
-        if dp > 1 and not dp_active:
-            lowered = self._lower_for(chunks)
-            D = lowered.num_devices
+        dp_active = dp > 1
+        if dp_active and jax.device_count() < dp * D:
+            raise ValueError(
+                f"data_parallel={dp} x a {D}-device ring needs {dp * D} "
+                f"devices; this host has {jax.device_count()}"
+            )
         self._lowered[chunks] = lowered
         self._data_parallel_active = dp_active
         d_travel = travel_width(self._bounds, widths)
 
-        spmd = dp_active or jax.device_count() >= D
+        spmd = jax.device_count() >= D
+        vary = ("data", "stage") if dp_active else ("stage",) if spmd else ()
         phases = set(np.unique(lowered.phase).tolist())
 
         def local(params, graph, labels, m, rng):
@@ -1028,7 +1050,7 @@ class CompiledGNNPipeline(PipelineEngine):
                 offset = lax.axis_index("data") * (chunks // dp)
             work_fn = self._make_work_fn(
                 widths, params, graph, labels, m, rng, phases=phases,
-                chunk_offset=offset,
+                chunk_offset=offset, vary=vary,
             )
             wire_like = jnp.zeros(
                 (graph.features.shape[1], d_travel), graph.features.dtype
@@ -1051,18 +1073,14 @@ class CompiledGNNPipeline(PipelineEngine):
                 # row occupies which ring position
                 grid = grid[:, list(p.device_order)]
             mesh = jax.sharding.Mesh(grid, ("data", "stage"))
-            # check_vma=False: the executor's post-scan all_gather leaves the
-            # gathered slots marked varying over "data" even though every
-            # replica then reduces them to the same value; the old-API
-            # shard_map (check_rep=False) never tracked this at all
-            mapped = compat.shard_map(
+            mapped = jax.shard_map(
                 local, mesh=mesh,
                 in_specs=(P(), P("data"), P("data"), P("data"), P()),
-                out_specs=P(), check_vma=False,
+                out_specs=P(),
             )
         elif spmd:
             mesh = jax.sharding.Mesh(self._mesh_devices(D), ("stage",))
-            mapped = compat.shard_map(
+            mapped = jax.shard_map(
                 local, mesh=mesh, in_specs=(P(),) * 5, out_specs=P()
             )
         else:
@@ -1101,6 +1119,7 @@ class CompiledGNNPipeline(PipelineEngine):
         d_travel = travel_width(self._bounds, widths)
         model, bounds = self.model, self._bounds
         spmd = jax.device_count() >= D
+        vary = ("stage",) if spmd else ()
 
         def local(params, graph):
             # train=False: dropout is the identity, the rng is never consumed
@@ -1125,7 +1144,7 @@ class CompiledGNNPipeline(PipelineEngine):
 
             def work_fn(phase, stage, chunk, h_in):
                 index = jnp.where(phase == 0, 0, stage + 1)
-                return lax.switch(index, branches, (chunk, h_in))
+                return lax.switch(index, _varying(branches, vary), (chunk, h_in))
 
             if spmd:
                 return spmd_pipeline_scheduled_eval(
@@ -1139,7 +1158,7 @@ class CompiledGNNPipeline(PipelineEngine):
         if spmd:
             devs = self._mesh_devices(D) if D == S else np.array(jax.devices()[:D])
             mesh = jax.sharding.Mesh(devs, ("stage",))
-            mapped = compat.shard_map(
+            mapped = jax.shard_map(
                 local, mesh=mesh, in_specs=(P(), P()), out_specs=P()
             )
         else:
@@ -1208,6 +1227,24 @@ class CompiledGNNPipeline(PipelineEngine):
     ):
         """One fused SPMD step over the stacked plan (compiled per
         ``(chunks, n_pad, max_deg, optimizer)`` shape key and cached)."""
+        step, args = self.step_program(
+            params, opt_state, plan, rng, optimizer, stats=stats
+        )
+        return step(*args)
+
+    def step_program(
+        self,
+        params: list,
+        opt_state,
+        plan: MicroBatchPlan,
+        rng: jax.Array,
+        optimizer: opt_lib.Optimizer,
+        *,
+        stats: dict | None = None,
+    ):
+        """``(jitted step, its arguments)`` for one ``train_step``: call the
+        one on the other to step, or ``.lower(*args)`` it to read the
+        compiled program."""
         stacked = plan.stacked()
         graph = self.layout(stacked.graph)
         if self._widths is None:
@@ -1257,11 +1294,11 @@ class CompiledGNNPipeline(PipelineEngine):
                 stats["num_ticks"] = lowered.num_ticks
                 stats["wire_latency"] = lowered.wire_latency
         if self._fill_drain:
-            return step(
+            return step, (
                 params, opt_state, travel, graph, stacked.graph.labels,
                 loss_mask, rng,
             )
-        return step(
+        return step, (
             params, opt_state, graph, stacked.graph.labels, loss_mask, rng
         )
 

@@ -457,7 +457,8 @@ def spmd_pipeline_scheduled(
         (zero_wire,) * (4 if double else 2), fstash0, bstash0, wstash0, gbuf0,
         jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32),
     )
-    carry0 = match_vma(carry0, grads_like, vma_refs, extra=(stage_axis,))
+    axes = (stage_axis,) if data_axis is None else (data_axis, stage_axis)
+    carry0 = match_vma(carry0, grads_like, vma_refs, extra=axes)
     (_, _, _, _, gbuf, loss, count), _ = lax.scan(tick_body, carry0, jnp.arange(T))
 
     # canonical reduction: per layer, chunks in DESCENDING order — the host
@@ -472,13 +473,22 @@ def spmd_pipeline_scheduled(
         # reduce over GLOBAL chunks in the same descending order a single
         # replica would use: global chunk r*C + c descends as (r, c) descends
         # lexicographically. Exact, not just close — see the docstring.
-        gall = tree_map(lambda b: lax.all_gather(b, data_axis), gbuf)
-        dp = jax.tree_util.tree_leaves(gall)[0].shape[0]
+        dp = lax.axis_size(data_axis)
+        r_self = lax.axis_index(data_axis)
+
+        def gather(x):
+            # an all_gather written as a psum of zero-padded slots: each slot
+            # has one nonzero addend, so the sum is exact, and the result is
+            # invariant over the data axis, as a replicated output must be
+            slots = jnp.zeros((dp,) + x.shape, x.dtype)
+            return lax.psum(lax.dynamic_update_index_in_dim(slots, x, r_self, 0), data_axis)
+
+        gall = tree_map(gather, gbuf)
         for r in reversed(range(dp)):
             for c in reversed(range(C)):
                 grads = tree_map(lambda g, b, r=r, c=c: g + b[r, c], grads, gall)
-        loss = jnp.sum(lax.all_gather(loss, data_axis))
-        count = jnp.sum(lax.all_gather(count, data_axis))
+        loss = jnp.sum(gather(loss))
+        count = jnp.sum(gather(count))
     grads = lax.psum(grads, stage_axis)
     loss = lax.psum(loss, stage_axis)
     count = lax.psum(count, stage_axis)

@@ -1,21 +1,19 @@
-"""Fused GAT neighbor attention — Pallas TPU kernels.
+"""Fused GAT neighbor attention — Pallas TPU kernel.
 
-One VMEM-resident fusion of score → masked softmax → weighted aggregate over
-the padded-neighbor layout (DESIGN.md §3): the (N, D, H) attention tensor is
-never materialized in HBM (the paper's DGL/PyG backends materialize it and
-make two extra passes).
+One VMEM-resident fusion of score → masked softmax → weighted aggregate
+(DESIGN.md §3): the per-edge attention tensor is never materialized in HBM
+(the paper's DGL/PyG backends materialize it and make two extra passes).
 
-``gat_aggregate_kernel`` (padded layout): the neighbor gather stays in XLA —
-upstream materializes the gathered ``(H, N, D, F)`` tensor. Blocking: grid
-(H, N/T); each step holds (T, D, F) neighbor features + (T, D) scores in
-VMEM; the weighted sum is a (T,D)×(T,D,F) batched contraction on the MXU.
-
-``bucket_gat_kernel`` (degree-bucketed layout): the feature gather moves
-INSIDE the kernel — the bucket's neighbor indices ride scalar-prefetch
-(SMEM) and drive dynamic row loads out of a per-head VMEM-resident (N, F)
-feature block, so the ``(R, W, H, F)`` gathered tensor never exists in HBM
-at all. Scores are still gathered in XLA (no F factor — (H, R, W) is small).
-Grid (H, R/T), one launch per degree bucket.
+``bucket_gat_kernel`` runs over one degree bucket's rectangular tile
+(``graphs.partition.degree_bucketed_layout``); the padded layout is the
+square bucket with one row per node, so ``ops.gat_aggregate`` calls the same
+kernel. The feature gather happens INSIDE the kernel: each tile's neighbor
+indices ride into SMEM and drive dynamic row loads out of the current
+head's VMEM-resident (N, F) feature block, so the gathered ``(R, W, H, F)``
+tensor never exists in HBM. Scores are gathered in XLA (no F factor —
+(H, R, W) is small). Grid (H, R/T), one launch per bucket; the weighted
+sum is the slot-major gather-accumulate shared with the SpMM kernel
+(``spmm.kernel.aggregate_tile``).
 """
 
 from __future__ import annotations
@@ -28,147 +26,63 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import runtime_interpret
+from repro.kernels.spmm.kernel import (
+    aggregate_tile,
+    check_resident_vmem,
+    compiler_params,
+    tile_rows,
+)
 
 _NEG = -1e9
 
 
-def _kernel(s_self_ref, s_nbr_ref, mask_ref, nbr_ref, out_ref, *, negative_slope):
-    # blocks: s_self (1, T); s_nbr (1, T, D); mask (T, D); nbr (1, T, D, F)
-    s_self = s_self_ref[0]  # (T,)
-    s_nbr = s_nbr_ref[0]  # (T, D)
-    mask = mask_ref[...]  # (T, D)
-    nbr = nbr_ref[0]  # (T, D, F)
-
-    s = s_self[:, None] + s_nbr
-    s = jnp.where(s >= 0, s, negative_slope * s).astype(jnp.float32)
-    s = jnp.where(mask, s, _NEG)
-    m = jnp.max(s, axis=1, keepdims=True)
-    p = jnp.exp(s - m) * mask
-    l = jnp.maximum(jnp.sum(p, axis=1, keepdims=True), 1e-30)
-    alpha = (p / l).astype(nbr.dtype)
-    # (T, 1, D) @ (T, D, F) -> (T, 1, F): batched MXU contraction over D
-    out = jax.lax.dot_general(
-        alpha[:, None, :], nbr,
-        dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )
-    out_ref[0] = out[:, 0].astype(out_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("negative_slope", "block_n", "interpret"))
-def _gat_call(nbr_hw, s_self, s_nbr, mask, *, negative_slope, block_n, interpret):
-    h, n, d, f = nbr_hw.shape
-    pad = (-n) % block_n
-    if pad:
-        nbr_hw = jnp.pad(nbr_hw, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        s_self = jnp.pad(s_self, ((0, 0), (0, pad)))
-        s_nbr = jnp.pad(s_nbr, ((0, 0), (0, pad), (0, 0)))
-        mask = jnp.pad(mask, ((0, pad), (0, 0)))
-    n_pad = n + pad
-
-    grid = (h, n_pad // block_n)
-    out = pl.pallas_call(
-        functools.partial(_kernel, negative_slope=negative_slope),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_n), lambda hh, i: (hh, i)),
-            pl.BlockSpec((1, block_n, d), lambda hh, i: (hh, i, 0)),
-            pl.BlockSpec((block_n, d), lambda hh, i: (i, 0)),
-            pl.BlockSpec((1, block_n, d, f), lambda hh, i: (hh, i, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_n, f), lambda hh, i: (hh, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((h, n_pad, f), nbr_hw.dtype),
-        interpret=interpret,
-    )(s_self, s_nbr, mask, nbr_hw)
-    return out[:, :n]
-
-
-def gat_aggregate_kernel(
-    nbr_hw: jax.Array,  # (H, N, D, F)
-    s_self: jax.Array,  # (H, N)
-    s_nbr: jax.Array,  # (H, N, D)
-    mask: jax.Array,  # (N, D)
-    *,
-    negative_slope: float = 0.2,
-    block_n: int = 128,
-    interpret: bool | None = None,  # None -> kernels.runtime_interpret()
-) -> jax.Array:
-    if interpret is None:
-        interpret = runtime_interpret()
-    return _gat_call(
-        nbr_hw, s_self, s_nbr, mask,
-        negative_slope=negative_slope, block_n=block_n, interpret=interpret,
-    )
-
-
 def _bucket_kernel(
-    nbr_ref, s_self_ref, s_nbr_ref, mask_ref, hw_ref, out_ref,
-    *, block_r, width, negative_slope,
+    nbr_ref, s_self_ref, s_nbr_ref, mask_ref, hw_ref, out_ref, gather_ref,
+    *, negative_slope,
 ):
-    # blocks: s_self (1, T); s_nbr (1, T, W); mask (T, W); hw (N, F) — the
-    # current head's full feature matrix, resident in VMEM. nbr_ref is the
-    # whole (R_pad, W) index array in SMEM (scalar prefetch).
-    i = pl.program_id(1)
-
-    # vectorized masked softmax over the whole (T, W) tile
-    s = s_self_ref[0][:, None] + s_nbr_ref[0]
+    # blocks: nbr (T, W) in SMEM; s_self (T, 1); s_nbr (T, W); mask (T, W)
+    # as 0/1 floats; hw (N, F) — the current head's resident feature matrix
+    s = s_self_ref[...] + s_nbr_ref[...]
     s = jnp.where(s >= 0, s, negative_slope * s).astype(jnp.float32)
     mask = mask_ref[...]
-    s = jnp.where(mask, s, _NEG)
+    s = jnp.where(mask > 0, s, _NEG)
     m = jnp.max(s, axis=1, keepdims=True)
     p = jnp.exp(s - m) * mask
     l = jnp.maximum(jnp.sum(p, axis=1, keepdims=True), 1e-30)
     alpha = p / l  # (T, W) f32
-
-    def row_body(t, _):
-        gi = i * block_r + t  # global bucket row (rows padded to grid)
-        acc = jnp.zeros((hw_ref.shape[1],), jnp.float32)
-
-        def nbr_body(j, acc):
-            idx = nbr_ref[gi, j]  # scalar from SMEM prefetch
-            row = pl.load(hw_ref, (pl.dslice(idx, 1), slice(None)))[0]
-            return acc + alpha[t, j] * row.astype(jnp.float32)
-
-        acc = jax.lax.fori_loop(0, width, nbr_body, acc)
-        out_ref[0, t, :] = acc.astype(out_ref.dtype)
-        return 0
-
-    jax.lax.fori_loop(0, block_r, row_body, 0)
+    out_ref[...] = aggregate_tile(nbr_ref, hw_ref, gather_ref, alpha).astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("negative_slope", "block_r", "interpret"))
 def _bucket_gat_call(hw_heads, neighbors, s_self, s_nbr, mask, *, negative_slope, block_r, interpret):
     h, n, f = hw_heads.shape
     r, w = neighbors.shape
+    check_resident_vmem(n, f, hw_heads.dtype, what="GAT kernel")
+    block_r = tile_rows(block_r, w, what="GAT kernel")
     pad = (-r) % block_r
-    if pad:
-        neighbors = jnp.pad(neighbors, ((0, pad), (0, 0)))
-        s_self = jnp.pad(s_self, ((0, 0), (0, pad)))
-        s_nbr = jnp.pad(s_nbr, ((0, 0), (0, pad), (0, 0)))
-        mask = jnp.pad(mask, ((0, pad), (0, 0)))
+    neighbors = jnp.pad(neighbors, ((0, pad), (0, 0)))
+    s_self = jnp.pad(s_self, ((0, 0), (0, pad)))[..., None]  # (H, R_pad, 1)
+    s_nbr = jnp.pad(s_nbr, ((0, 0), (0, pad), (0, 0)))
+    mask = jnp.pad(mask, ((0, pad), (0, 0))).astype(jnp.float32)
     r_pad = r + pad
 
-    # head-major flatten so a (n, f) block indexed by head is one reshape away
-    hw_flat = hw_heads.reshape(h * n, f)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+    out = pl.pallas_call(
+        functools.partial(_bucket_kernel, negative_slope=negative_slope),
         grid=(h, r_pad // block_r),
         in_specs=[
-            pl.BlockSpec((1, block_r), lambda hh, i, nbr: (hh, i)),
-            pl.BlockSpec((1, block_r, w), lambda hh, i, nbr: (hh, i, 0)),
-            pl.BlockSpec((block_r, w), lambda hh, i, nbr: (i, 0)),
-            pl.BlockSpec((n, f), lambda hh, i, nbr: (hh, 0)),  # head hh's (N, F)
+            pl.BlockSpec((block_r, w), lambda hh, i: (i, 0), memory_space=pltpu.SMEM),
+            pl.BlockSpec((None, block_r, 1), lambda hh, i: (hh, i, 0)),
+            pl.BlockSpec((None, block_r, w), lambda hh, i: (hh, i, 0)),
+            pl.BlockSpec((block_r, w), lambda hh, i: (i, 0)),
+            pl.BlockSpec((None, n, f), lambda hh, i: (hh, 0, 0)),  # head hh's (N, F)
         ],
-        out_specs=pl.BlockSpec((1, block_r, f), lambda hh, i, nbr: (hh, i, 0)),
-    )
-    out = pl.pallas_call(
-        functools.partial(
-            _bucket_kernel, block_r=block_r, width=w, negative_slope=negative_slope
-        ),
-        grid_spec=grid_spec,
+        out_specs=pl.BlockSpec((None, block_r, f), lambda hh, i: (hh, i, 0)),
         out_shape=jax.ShapeDtypeStruct((h, r_pad, f), hw_heads.dtype),
+        scratch_shapes=[pltpu.VMEM((block_r, f), hw_heads.dtype)],
+        compiler_params=compiler_params(),
         interpret=interpret,
-    )(neighbors, s_self, s_nbr, mask, hw_flat)
+        name="gat_aggregate",
+    )(neighbors, s_self, s_nbr, mask, hw_heads)
     return out[:, :r]
 
 

@@ -1,15 +1,14 @@
 """Public ops for the fused GAT attention kernels (+ custom VJP).
 
 ``gat_aggregate`` takes the UNgathered layer tensors (matching the layer
-call-site in ``repro.models.gnn.layers``), performs the neighbor gather in
-XLA, and runs the fused kernel forward over the padded layout.
+call-site in ``repro.models.gnn.layers``) and runs the fused kernel forward
+over the padded layout — the square bucket, one row per node.
 
 ``bucketed_gat_aggregate`` is the degree-bucketed variant: per-bucket
 rectangular tiles (see ``graphs.partition.degree_bucketed_layout``), one
-kernel launch per non-empty bucket, and — unlike the padded path — the
-feature gather happens INSIDE the kernel, so the gathered ``(R, W, H, F)``
-tensor is never materialized by XLA. Score gathers (no F factor) stay in
-XLA.
+kernel launch per non-empty bucket. Either way the feature gather happens
+INSIDE the kernel, so the gathered ``(R, W, H, F)`` tensor is never
+materialized by XLA. Score gathers (no F factor) stay in XLA.
 
 Forward routing follows ``kernels.use_kernel_forward()`` (Pallas kernel on
 TPU / forced, jnp oracle elsewhere); backward re-derives the vjp from the
@@ -25,7 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import use_kernel_forward
-from repro.kernels.gat_edge.kernel import bucket_gat_kernel, gat_aggregate_kernel
+from repro.kernels.gat_edge.kernel import bucket_gat_kernel
 from repro.kernels.gat_edge.ref import bucket_gat_ref, gat_aggregate_ref
 
 
@@ -37,19 +36,24 @@ def _prepare(hw, s_src, s_dst, neighbors):
     return nbr_hw, s_self, s_nbr
 
 
+def _bucket_inputs(s_src, s_dst, nbr, row):
+    # per-bucket score gathers (XLA-side: no F factor, (H, R, W) is small)
+    s_self = s_src[row].T  # (H, R)
+    s_nbr = jnp.moveaxis(s_dst[nbr], 2, 0)  # (H, R, W)
+    return s_self, s_nbr
+
+
 @partial(jax.custom_vjp, nondiff_argnums=(5,))
 def gat_aggregate(hw, s_src, s_dst, neighbors, mask, negative_slope=0.2):
     """(N, H, F) aggregated outputs over the padded layout."""
-    nbr_hw, s_self, s_nbr = _prepare(hw, s_src, s_dst, neighbors)
     if use_kernel_forward():
-        out = gat_aggregate_kernel(
-            nbr_hw, s_self, s_nbr, mask, negative_slope=negative_slope
+        s_self, s_nbr = _bucket_inputs(s_src, s_dst, neighbors, slice(None))
+        out = bucket_gat_kernel(
+            jnp.moveaxis(hw, 1, 0), neighbors, s_self, s_nbr, mask,
+            negative_slope=negative_slope,
         )
-    else:
-        out = gat_aggregate_ref(
-            nbr_hw, s_self, s_nbr, mask, negative_slope=negative_slope
-        )
-    return jnp.moveaxis(out, 0, 1)  # (N, H, F)
+        return jnp.moveaxis(out, 0, 1)  # (N, H, F)
+    return _ref_call(hw, s_src, s_dst, neighbors, mask, negative_slope)
 
 
 def _ref_call(hw, s_src, s_dst, neighbors, mask, negative_slope):
@@ -77,13 +81,6 @@ def _bwd(negative_slope, res, ct):
 
 
 gat_aggregate.defvjp(_fwd, _bwd)
-
-
-def _bucket_inputs(s_src, s_dst, nbr, row):
-    # per-bucket score gathers (XLA-side: no F factor, (H, R, W) is small)
-    s_self = s_src[row].T  # (H, R)
-    s_nbr = jnp.moveaxis(s_dst[nbr], 2, 0)  # (H, R, W)
-    return s_self, s_nbr
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(7,))

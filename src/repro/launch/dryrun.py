@@ -34,7 +34,7 @@ from repro.models.transformer.model import (
     make_serve_step,
     make_train_step,
 )
-from repro.roofline.analysis import model_flops, roofline_report
+from repro.roofline.analysis import model_flops, peaks_for, roofline_report
 from repro.roofline.hlo_walk import analyze_hlo
 
 
@@ -105,6 +105,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool, out_dir: str | None,
         device_collective=walk["collectives"],
         chips=chips,
         model_flops_global=mf,
+        hw=peaks_for("TPU v5 lite"),  # the placeholder devices model v5e
     )
     result = {
         "arch": arch,

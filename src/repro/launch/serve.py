@@ -14,6 +14,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def run(args) -> dict:
     from repro.configs import get_arch, ShapeConfig
@@ -103,7 +105,9 @@ def main():
     ap.add_argument("--stages", type=int, default=1)
     ap.add_argument("--chunks", type=int, default=2)
     ap.add_argument("--seed", type=int, default=0)
-    run(ap.parse_args())
+    args = ap.parse_args()
+    enable_compile_cache()
+    run(args)
 
 
 if __name__ == "__main__":

@@ -47,6 +47,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def run_gnn(args) -> dict:
     from repro.core.cli import PipelineCLIConfig
@@ -360,7 +362,8 @@ def run_lm(args) -> dict:
     return out
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
+    """The training CLI's flags."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--mode", choices=["gnn", "lm"], default="gnn")
     ap.add_argument("--dataset", default="cora")
@@ -383,13 +386,18 @@ def main():
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
-    args = ap.parse_args()
+    return ap
+
+
+def main():
+    args = build_parser().parse_args()
     if getattr(args, "overlap", "off") == "async":
         # must land in XLA_FLAGS before the first backend touch — main() is
         # the one place that runs ahead of any jax.devices() call
         from repro.core.overlap_report import apply_async_overlap_flags
 
         apply_async_overlap_flags()
+    enable_compile_cache()
     if args.mode == "gnn":
         run_gnn(args)
     else:

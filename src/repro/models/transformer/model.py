@@ -30,9 +30,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
-from repro.core import compat
 from repro.configs.base import ArchConfig, ShapeConfig, pipeline_padding
 from repro.core.spmd_pipe import (
     make_gather_fn,
@@ -516,6 +515,15 @@ def _labels_from_batch(cfg: ArchConfig, batch: dict, seq: int) -> tuple[jax.Arra
     return labels, (labels >= 0).astype(jnp.float32)
 
 
+def _auto_axes(mesh):
+    """``mesh`` with Auto axes. The step builders place values with
+    ``with_sharding_constraint``, which Explicit axes (``jax.make_mesh``'s
+    default) refuse."""
+    return jax.sharding.Mesh(
+        mesh.devices, mesh.axis_names, axis_types=(AxisType.Auto,) * len(mesh.axis_names)
+    )
+
+
 def make_train_step(
     cfg: ArchConfig,
     topo: Topology,
@@ -525,6 +533,7 @@ def make_train_step(
     lr: float = 1e-4,
     dtype=jnp.bfloat16,
 ) -> StepArtifacts:
+    mesh = _auto_axes(mesh)
     seq = shape.seq_len
     positions = make_positions(cfg, seq)
     extras = make_extras(cfg, topo.num_stages)
@@ -599,7 +608,7 @@ def make_train_step(
             yspec = P(topo.data_axes, None, None)
         else:
             yspec = P(topo.data_axes, topo.stage_axis, None)
-        y = compat.shard_map(
+        y = jax.shard_map(
             pipe_body,
             mesh=mesh,
             in_specs=(specs["blocks"], shared_spec, ex_specs, xspec),
@@ -748,6 +757,7 @@ def make_serve_step(
     dtype=jnp.bfloat16,
 ) -> StepArtifacts:
     """One decode step: next-token logits + cache update, pipelined."""
+    mesh = _auto_axes(mesh)
     extras = make_extras(cfg, topo.num_stages, long_context=topo.seq_shard_decode)
     aparams = _abstract_params(cfg, topo, dtype)
     specs, gather_mask = param_layout(cfg, aparams, topo)
@@ -786,7 +796,7 @@ def make_serve_step(
         # batch-replicated decode (long_500k): the cache is genuinely
         # invariant over idle mesh axes but shard_map cannot infer it
         # through the gathered-param dataflow — skip the static check.
-        y, cache = compat.shard_map(
+        y, cache = jax.shard_map(
             pipe_body,
             mesh=mesh,
             in_specs=(specs["blocks"], shared_spec, ex_specs, cache_specs, xspec, P()),
@@ -818,6 +828,7 @@ def make_prefill_step(
     dtype=jnp.bfloat16,
 ) -> StepArtifacts:
     """Full-sequence prefill: last-token logits + populated KV cache."""
+    mesh = _auto_axes(mesh)
     seq = shape.seq_len
     positions = make_positions(cfg, seq)
     extras = make_extras(cfg, topo.num_stages)
@@ -853,7 +864,7 @@ def make_prefill_step(
         shared = params.get("shared_attn", ())
         shared_spec = specs.get("shared_attn", ())
         yspec = P(topo.data_axes, topo.stage_axis, None)
-        y, cache = compat.shard_map(
+        y, cache = jax.shard_map(
             pipe_body,
             mesh=mesh,
             in_specs=(specs["blocks"], shared_spec, ex_specs, cache_specs, xspec),

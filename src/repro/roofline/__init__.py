@@ -1,6 +1,8 @@
 from repro.roofline.analysis import (
     HW,
+    PEAKS,
     collective_bytes,
+    peaks_for,
     roofline_report,
     model_flops,
 )
@@ -13,6 +15,8 @@ from repro.roofline.stage_report import (
 
 __all__ = [
     "HW",
+    "PEAKS",
+    "peaks_for",
     "collective_bytes",
     "roofline_report",
     "model_flops",

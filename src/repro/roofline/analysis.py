@@ -19,13 +19,31 @@ from __future__ import annotations
 import dataclasses
 import re
 
-# TPU v5e, per assignment
 @dataclasses.dataclass(frozen=True)
 class HW:
-    peak_flops: float = 197e12  # bf16 FLOP/s per chip
-    hbm_bw: float = 819e9  # bytes/s per chip
-    ici_bw: float = 50e9  # bytes/s per link
-    ici_links: int = 4  # usable links/chip on a 2D torus (2 axes × 2 dirs)
+    """Published peaks of one chip."""
+
+    peak_flops: float  # bf16 FLOP/s per chip
+    hbm_bw: float  # bytes/s per chip
+    ici_bw: float  # bytes/s per link
+    ici_links: int  # usable links/chip on a 2D torus (2 axes × 2 dirs)
+
+
+# Keyed by ``jax.Device.device_kind``. TPU v5e: Google Cloud documentation,
+# "TPU v5e" (197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s ICI over 4 links).
+PEAKS = {
+    "TPU v5 lite": HW(peak_flops=197e12, hbm_bw=819e9, ici_bw=50e9, ici_links=4),
+}
+
+
+def peaks_for(device_kind: str) -> HW:
+    """The peaks of ``device_kind``; a kind not in ``PEAKS`` is an error."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r}; known: {sorted(PEAKS)}"
+        ) from None
 
 
 _DTYPE_BYTES = {
@@ -100,7 +118,7 @@ def roofline_report(
     device_collective: dict[str, int],
     chips: int,
     model_flops_global: float,
-    hw: HW = HW(),
+    hw: HW,
 ) -> dict:
     compute_s = device_flops / hw.peak_flops
     memory_s = device_bytes / hw.hbm_bw

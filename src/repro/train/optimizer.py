@@ -115,6 +115,22 @@ def sgd(lr: float | Callable, *, momentum: float = 0.0) -> Optimizer:
     return Optimizer(init=init, update=update)
 
 
+def grad_probe() -> Optimizer:
+    """Leaves params unchanged and keeps the gradients as its state: after
+    one ``train_step`` the returned optimizer state IS the step's gradient
+    pytree, so engines can be compared on gradients rather than on an
+    update (Adam's first update is about ``lr * sign(g)`` whatever g's size)."""
+
+    def init(params):
+        return jax.tree_util.tree_map(jnp.zeros_like, params)
+
+    def update(grads, state, params):
+        del state
+        return jax.tree_util.tree_map(jnp.zeros_like, params), grads
+
+    return Optimizer(init=init, update=update)
+
+
 def cosine_schedule(peak: float, *, warmup: int, total: int, floor: float = 0.0):
     def sched(step):
         step = step.astype(jnp.float32)

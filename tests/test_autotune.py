@@ -269,6 +269,23 @@ def test_make_engine_accepts_plan_and_to_config_overrides():
 # ------------------------------------------------ cached profiler sidecar --
 
 
+def test_explicit_pipe_devices_beyond_host_is_an_error():
+    """An explicit --pipe-devices the host cannot give the compiled engine
+    raises; the default 2-device interleaved ring may still run on the
+    one-device lane substrate."""
+    too_many = jax.device_count() + 1
+    cli = PipelineCLIConfig(engine="compiled", schedule="interleaved",
+                            stages=4, chunks=4, pipe_devices=too_many)
+    with pytest.raises(ValueError, match="--pipe-devices"):
+        cli.gpipe_config()
+    default = PipelineCLIConfig(engine="compiled", schedule="interleaved",
+                                stages=4, chunks=4)
+    assert default.gpipe_config().num_devices == 2
+    host = PipelineCLIConfig(engine="host", schedule="interleaved",
+                             stages=4, chunks=4, pipe_devices=too_many)
+    assert host.gpipe_config().num_devices == too_many
+
+
 def test_cached_profile_sidecar_roundtrip(tmp_path):
     """First call profiles and writes the JSON sidecar; a cold process
     (in-process cache cleared) reads the sidecar back instead of
@@ -309,6 +326,24 @@ def test_profile_fingerprint_keys_on_shape_and_backend():
     k1 = profile_fingerprint(m, params, g, "padded")
     assert k1 == profile_fingerprint(m, params, g, "padded")
     assert k1 != profile_fingerprint(m, params, g, "bucketed")
+
+
+def test_profile_fingerprint_keys_on_device(monkeypatch):
+    """A profile measured on one kind of device never keys a lookup on
+    another: the CPU sidecar cannot drive --auto on the chip."""
+    import types
+
+    g = load_dataset("karate")
+    m = build_paper_gat(g.num_features, g.num_classes)
+    params = m.init_params(jax.random.PRNGKey(0))
+    k_here = profile_fingerprint(m, params, g, "padded")
+    keys = {}
+    for platform, kind in (("tpu", "TPU v5 lite"), ("tpu", "TPU v4"), ("cpu", "cpu")):
+        chip = types.SimpleNamespace(platform=platform, device_kind=kind)
+        monkeypatch.setattr(jax, "devices", lambda *a, chip=chip: [chip])
+        keys[platform, kind] = profile_fingerprint(m, params, g, "padded")
+    assert len(set(keys.values())) == 3
+    assert k_here == keys["cpu", "cpu"]
 
 
 # ------------------------------------------------- --auto --dry-run table --

@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st  # optional-hypothesis shim
 
-from repro.kernels.gat_edge.kernel import gat_aggregate_kernel
+from repro.kernels.gat_edge.kernel import bucket_gat_kernel
 from repro.kernels.gat_edge.ref import gat_aggregate_ref
 from repro.kernels.gat_edge.ops import gat_aggregate, _ref_call
 from repro.kernels.spmm.kernel import padded_spmm_kernel
@@ -26,13 +26,15 @@ def _tol(dtype):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("n,d,h,f", [(64, 4, 2, 8), (300, 9, 8, 8), (130, 16, 4, 16)])
 def test_gat_kernel_shapes(n, d, h, f, dtype):
+    # the padded layout is the square bucket: one kernel row per node
     k = jax.random.PRNGKey(n + d)
-    nbr_hw = jax.random.normal(k, (h, n, d, f), dtype)
+    hw_heads = jax.random.normal(k, (h, n, f), dtype)
+    nbr = jax.random.randint(jax.random.PRNGKey(4), (n, d), 0, n)
     s_self = jax.random.normal(jax.random.PRNGKey(1), (h, n), dtype)
     s_nbr = jax.random.normal(jax.random.PRNGKey(2), (h, n, d), dtype)
     mask = jax.random.bernoulli(jax.random.PRNGKey(3), 0.7, (n, d)).at[:, 0].set(True)
-    out_k = gat_aggregate_kernel(nbr_hw, s_self, s_nbr, mask)
-    out_r = gat_aggregate_ref(nbr_hw, s_self, s_nbr, mask)
+    out_k = bucket_gat_kernel(hw_heads, nbr, s_self, s_nbr, mask)
+    out_r = gat_aggregate_ref(hw_heads[:, nbr], s_self, s_nbr, mask)
     np.testing.assert_allclose(
         np.asarray(out_k, np.float32), np.asarray(out_r, np.float32), atol=_tol(dtype)
     )
@@ -48,12 +50,13 @@ def test_gat_kernel_shapes(n, d, h, f, dtype):
 )
 def test_gat_kernel_hypothesis(n, d, h, f, seed):
     k = jax.random.PRNGKey(seed)
-    nbr_hw = jax.random.normal(k, (h, n, d, f))
+    hw_heads = jax.random.normal(k, (h, n, f))
+    nbr = jax.random.randint(jax.random.fold_in(k, 4), (n, d), 0, n)
     s_self = jax.random.normal(jax.random.fold_in(k, 1), (h, n))
     s_nbr = jax.random.normal(jax.random.fold_in(k, 2), (h, n, d))
     mask = jax.random.bernoulli(jax.random.fold_in(k, 3), 0.6, (n, d)).at[:, 0].set(True)
-    out_k = gat_aggregate_kernel(nbr_hw, s_self, s_nbr, mask, block_n=64)
-    out_r = gat_aggregate_ref(nbr_hw, s_self, s_nbr, mask)
+    out_k = bucket_gat_kernel(hw_heads, nbr, s_self, s_nbr, mask, block_r=64)
+    out_r = gat_aggregate_ref(hw_heads[:, nbr], s_self, s_nbr, mask)
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r), atol=1e-4)
 
 

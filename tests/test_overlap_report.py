@@ -7,6 +7,8 @@ import gzip
 import json
 import os
 
+import pytest
+
 from repro.core.overlap_report import (
     ASYNC_XLA_FLAGS,
     capture_overlap_report,
@@ -101,16 +103,16 @@ def test_load_trace_events_reads_gzipped_chrome_traces(tmp_path):
 
 
 def test_capture_overlap_report_degrades_on_error(tmp_path):
-    """A step_fn that raises must yield a zeroed report with an ``error``
-    field (the bench keeps timing; the gate falls back to tick bounds),
-    and the trace dir is still reported for upload."""
+    """A step_fn that raises propagates its error out of the capture —
+    a zeroed report would read as a measured absence of overlap — and the
+    profiler is stopped again, so the next capture can start."""
     def boom():
         raise RuntimeError("no step")
 
-    r = capture_overlap_report(boom, trace_dir=str(tmp_path / "t"))
-    assert r["overlap_fraction"] == 0.0
-    assert "RuntimeError" in r["error"]
-    assert r["trace_dir"] == str(tmp_path / "t")
+    with pytest.raises(RuntimeError, match="no step"):
+        capture_overlap_report(boom, trace_dir=str(tmp_path / "t"))
+    r = capture_overlap_report(lambda: None, trace_dir=str(tmp_path / "u"))
+    assert r["trace_dir"] == str(tmp_path / "u")
 
 
 def test_async_flags_are_verified_spellings():

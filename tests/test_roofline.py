@@ -4,7 +4,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.roofline.analysis import model_flops, roofline_report
+from repro.roofline.analysis import model_flops, peaks_for, roofline_report
 from repro.roofline.hlo_walk import analyze_hlo, parse_module
 from repro.configs import get_arch, get_shape
 
@@ -77,12 +77,19 @@ def test_roofline_report_terms():
         device_collective={"total": 0, "all-gather": 0},
         chips=256,
         model_flops_global=197e12 * 256 * 0.5,
+        hw=peaks_for("TPU v5 lite"),
     )
     assert rep["compute_s"] == pytest.approx(1.0)
     assert rep["memory_s"] == pytest.approx(1.0)
     assert rep["collective_s"] == 0.0
     assert rep["useful_flops_ratio"] == pytest.approx(0.5)
     assert rep["dominant"] in ("compute_s", "memory_s")
+
+
+def test_peaks_unknown_device_kind_is_an_error():
+    assert peaks_for("TPU v5 lite").peak_flops == 197e12
+    with pytest.raises(KeyError, match="cpu"):
+        peaks_for("cpu")
 
 
 def test_model_flops_train_vs_decode():

@@ -179,6 +179,7 @@ def _run(src: str, devices: int = 4, timeout: int = 1200):
     env = {
         "XLA_FLAGS": f"--xla_force_host_platform_device_count={devices}",
         "PYTHONPATH": "src",
+        "JAX_PLATFORMS": "cpu",
     }
     r = subprocess.run(
         [sys.executable, "-c", textwrap.dedent(src)],

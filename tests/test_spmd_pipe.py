@@ -3,6 +3,7 @@
 These run in subprocesses so the 8-device XLA flag never leaks into the
 main test process (smoke tests must see 1 device)."""
 
+import os
 import subprocess
 import sys
 import textwrap
@@ -14,22 +15,29 @@ def _run(src: str, devices: int = 8, timeout: int = 1200):
     env = {
         "XLA_FLAGS": f"--xla_force_host_platform_device_count={devices}",
         "PYTHONPATH": "src",
-        "PATH": "/usr/bin:/bin",
-        "HOME": "/root",
+        "JAX_PLATFORMS": "cpu",
     }
-    import os
-
-    env["PATH"] = os.environ.get("PATH", env["PATH"])
     r = subprocess.run(
-        [sys.executable, "-c", textwrap.dedent(src)],
+        [sys.executable, "-c", PRELUDE + textwrap.dedent(src)],
         capture_output=True,
         text=True,
         timeout=timeout,
         env={**os.environ, **env},
-        cwd="/root/repo",
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
     )
     assert r.returncode == 0, f"STDOUT:\n{r.stdout}\nSTDERR:\n{r.stderr[-4000:]}"
     return r.stdout
+
+
+# The hand-written programs below run on Auto mesh axes, not the Explicit
+# axes jax.make_mesh gives by default.
+PRELUDE = textwrap.dedent("""
+    import jax
+    from jax.sharding import AxisType
+
+    def auto_mesh(shape, names):
+        return jax.make_mesh(shape, names, axis_types=(AxisType.Auto,) * len(names))
+""")
 
 
 @pytest.mark.slow
@@ -37,15 +45,10 @@ def test_pipeline_fwd_grad_equivalence():
     out = _run("""
     import jax, jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    try:
-        shard_map = jax.shard_map
-        SM_KW = {}
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map
-        SM_KW = {"check_rep": False}  # old-jax scan-transpose rep tracking
+    shard_map = jax.shard_map
     from repro.core.spmd_pipe import spmd_pipeline, make_scanned_stage, make_gather_fn
 
-    mesh = jax.make_mesh((2, 4), ('data', 'model'))
+    mesh = auto_mesh((2, 4), ('data', 'model'))
     D, S, PER, NM, B = 16, 4, 2, 4, 8
     w = jax.random.normal(jax.random.PRNGKey(0), (S, PER, D, D)) * 0.3
     extras = {'active': jnp.ones((S, PER))}
@@ -68,7 +71,7 @@ def test_pipeline_fwd_grad_equivalence():
     f = jax.jit(shard_map(pipe, mesh=mesh,
         in_specs=({'w': P('model', None, 'data', None)}, {'active': P('model', None)},
                   P(None, 'data', None)),
-        out_specs=P(None, 'data', None), **SM_KW))
+        out_specs=P(None, 'data', None)))
     out = f({'w': w}, extras, x)
     ref = x
     for s in range(S):
@@ -95,15 +98,10 @@ def test_pipeline_scatter_dim_equivalence():
     out = _run("""
     import jax, jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    try:
-        shard_map = jax.shard_map
-        SM_KW = {}
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map
-        SM_KW = {"check_rep": False}  # old-jax scan-transpose rep tracking
+    shard_map = jax.shard_map
     from repro.core.spmd_pipe import spmd_pipeline, make_scanned_stage
 
-    mesh = jax.make_mesh((2, 4), ('data', 'model'))
+    mesh = auto_mesh((2, 4), ('data', 'model'))
     D, S, PER, NM, B, SEQ = 8, 4, 1, 2, 4, 8
     w = jax.random.normal(jax.random.PRNGKey(0), (S, PER, D, D)) * 0.3
     ex = {'active': jnp.ones((S, PER))}
@@ -123,7 +121,7 @@ def test_pipeline_scatter_dim_equivalence():
     f = jax.jit(shard_map(pipe, mesh=mesh,
         in_specs=({'w': P('model', None, None, None)}, {'active': P('model', None)},
                   P(None, 'data', None, None)),
-        out_specs=P(None, 'data', 'model', None), **SM_KW))
+        out_specs=P(None, 'data', 'model', None)))
     out = f({'w': w}, ex, x)   # (NM, mb, SEQ, D) with SEQ sharded over model
     ref = x
     for s in range(S):
@@ -140,15 +138,10 @@ def test_stateful_pipeline_cache_writes():
     out = _run("""
     import jax, jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    try:
-        shard_map = jax.shard_map
-        SM_KW = {}
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map
-        SM_KW = {"check_rep": False}  # old-jax scan-transpose rep tracking
+    shard_map = jax.shard_map
     from repro.core.spmd_pipe import spmd_pipeline, make_scanned_stage_stateful
 
-    mesh = jax.make_mesh((4,), ('model',))
+    mesh = auto_mesh((4,), ('model',))
     D, S, PER, NM, B = 8, 4, 1, 4, 4
     w = jax.random.normal(jax.random.PRNGKey(0), (S, PER, D, D)) * 0.3
     ex = {'active': jnp.ones((S, PER))}
@@ -169,7 +162,7 @@ def test_stateful_pipeline_cache_writes():
     f = jax.jit(shard_map(pipe, mesh=mesh,
         in_specs=({'w': P('model', None, None, None)}, {'active': P('model', None)},
                   P(None, None, None), P('model', None, None, None, None)),
-        out_specs=(P(None, None, None), P('model', None, None, None, None)), **SM_KW))
+        out_specs=(P(None, None, None), P('model', None, None, None, None))))
     out, st2 = f({'w': w}, ex, x, state)
     # stage 0's cached input for microbatch m must equal x[m]
     st0 = st2[0]   # (NM, PER, mb, D)
@@ -190,15 +183,10 @@ def test_interleaved_pipeline_fwd_grad_equivalence():
     out = _run("""
     import jax, jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    try:
-        shard_map = jax.shard_map
-        SM_KW = {}
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map
-        SM_KW = {"check_rep": False}  # old-jax scan-transpose rep tracking
+    shard_map = jax.shard_map
     from repro.core.spmd_pipe import spmd_pipeline_interleaved, make_interleaved_stage
 
-    mesh = jax.make_mesh((2,), ('model',))
+    mesh = auto_mesh((2,), ('model',))
     D, V, PER, NM, B, Dm = 2, 2, 2, 4, 8, 16
     S = D * V
     w = jax.random.normal(jax.random.PRNGKey(0), (S, PER, Dm, Dm)) * 0.3
@@ -224,7 +212,7 @@ def test_interleaved_pipeline_fwd_grad_equivalence():
 
     f = jax.jit(shard_map(pipe, mesh=mesh,
         in_specs=({'w': P('model')}, {'active': P('model')}, P()),
-        out_specs=P(), **SM_KW))
+        out_specs=P()))
     out = f(wp, ex, x)
     ref = x
     for k in range(S):
@@ -243,7 +231,7 @@ def test_interleaved_pipeline_fwd_grad_equivalence():
     assert jnp.allclose(g1['w'], g2, atol=1e-4), float(jnp.max(jnp.abs(g1['w'] - g2)))
 
     # C == D edge (same-tick buffer write/read) and deeper virtual stacks
-    mesh4 = jax.make_mesh((4,), ('model',))
+    mesh4 = auto_mesh((4,), ('model',))
     for D2, V2, NM2 in [(4, 2, 4), (4, 3, 8)]:
         S2 = D2 * V2
         w2 = jax.random.normal(jax.random.PRNGKey(2), (S2, 1, Dm, Dm)) * 0.3
@@ -257,7 +245,7 @@ def test_interleaved_pipeline_fwd_grad_equivalence():
             return spmd_pipeline_interleaved(sf, xm, stage_axis='model',
                                              num_devices=D, num_virtual=V, vma_refs=(wpp,))
         f2 = jax.jit(shard_map(pipe2, mesh=mesh4, in_specs=(P('model'), P()),
-                               out_specs=P(), **SM_KW))
+                               out_specs=P()))
         o2 = f2(ds(w2), x2)
         r2 = x2
         for k in range(S2):
