@@ -793,9 +793,13 @@ class CompiledGNNPipeline(PipelineEngine):
                 stage_fn, travel, stage_axis="stage", num_stages=S,
                 remat=remat, reduce="none",
             )
-            logp = out["h"][..., : model.out_dim]
-            nll = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
-            return lax.psum(jnp.sum(nll * m), "stage") / jnp.maximum(count, 1.0)
+            with jax.named_scope("pipe.loss"):
+                logp = out["h"][..., : model.out_dim]
+                nll = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
+                local_sum = jnp.sum(nll * m)
+            with jax.named_scope("pipe.wire"):
+                total = lax.psum(local_sum, "stage")
+            return total / jnp.maximum(count, 1.0)
 
         return local_loss
 
@@ -805,7 +809,7 @@ class CompiledGNNPipeline(PipelineEngine):
         nothing rides a wire). Same per-(chunk, layer) rng derivation and
         same masked-NLL accumulation as the pipelined program, so the update
         matches the ring substrate (and the host engine) exactly."""
-        model = self.model
+        model, bounds = self.model, self._bounds
         n_layers = len(model.layers)
         remat = self.config.remat
 
@@ -816,17 +820,21 @@ class CompiledGNNPipeline(PipelineEngine):
                 )
                 rngs = jax.random.split(jax.random.fold_in(rng, c), n_layers)
                 h = g.features
-                for i, layer in enumerate(model.layers):
-                    h = layer.apply(params[i], g, h, rngs[i], True)
-                nll = -jnp.take_along_axis(h, labels[c][:, None], axis=-1)[:, 0]
-                return jnp.sum(nll * m[c])
+                for s, (lo, hi) in enumerate(bounds):
+                    with jax.named_scope(f"pipe.fwd.s{s}"):
+                        for i in range(lo, hi):
+                            h = model.layers[i].apply(params[i], g, h, rngs[i], True)
+                with jax.named_scope("pipe.loss"):
+                    nll = -jnp.take_along_axis(h, labels[c][:, None], axis=-1)[:, 0]
+                    return jnp.sum(nll * m[c])
 
             body = jax.checkpoint(chunk_nll) if remat else chunk_nll
 
             def tick(acc, c):
                 return acc + body(c), None
 
-            lsum, _ = lax.scan(tick, jnp.zeros(()), travel["chunk"])
+            with jax.named_scope("pipe.exec"):
+                lsum, _ = lax.scan(tick, jnp.zeros(()), travel["chunk"])
             return lsum / jnp.maximum(count, 1.0)
 
         return scan_loss
@@ -851,8 +859,9 @@ class CompiledGNNPipeline(PipelineEngine):
             loss, grads = jax.value_and_grad(loss_fn)(
                 params, travel, graph, labels, m, count, rng
             )
-            updates, opt_state = optimizer.update(grads, opt_state, params)
-            params = opt_lib.apply_updates(params, updates)
+            with jax.named_scope("pipe.optimizer"):
+                updates, opt_state = optimizer.update(grads, opt_state, params)
+                params = opt_lib.apply_updates(params, updates)
             return params, opt_state, loss
 
         return jax.jit(step)
@@ -881,7 +890,13 @@ class CompiledGNNPipeline(PipelineEngine):
         params are cast to vary over them before any branch closes over
         them, so each ``jax.vjp`` yields this device's own gradients (no
         collective inside a branch), and every branch's outputs are cast
-        to the same axes so the switch sees one output type."""
+        to the same axes so the switch sees one output type.
+
+        Each branch runs its stage's own work under the named scope
+        ``pipe.<phase>.s<stage>`` (the phase words of the host engine's
+        ``record``), the loss head under ``pipe.loss``; the zero trees stay
+        outside, under the executor's ``pipe.exec``, so a device profile
+        splits stage work from executor overhead."""
         S = self.config.num_stages
         model = self.model
         params = match_vma(params, extra=vary)
@@ -896,12 +911,13 @@ class CompiledGNNPipeline(PipelineEngine):
         zero = jnp.zeros((), jnp.float32)
 
         def loss_ct(y, chunk):
-            logp = y[:, : model.out_dim]
-            (loss_sum, count), d_logp = jax.value_and_grad(
-                _chunk_loss_sum, argnums=0, has_aux=True
-            )(logp, labels[chunk], m[chunk])
-            ct = jnp.pad(d_logp, ((0, 0), (0, d_travel - d_logp.shape[-1])))
-            return ct, loss_sum, count
+            with jax.named_scope("pipe.loss"):
+                logp = y[:, : model.out_dim]
+                (loss_sum, count), d_logp = jax.value_and_grad(
+                    _chunk_loss_sum, argnums=0, has_aux=True
+                )(logp, labels[chunk], m[chunk])
+                ct = jnp.pad(d_logp, ((0, 0), (0, d_travel - d_logp.shape[-1])))
+                return ct, loss_sum, count
 
         b_fns, w_fns = make_gnn_stage_slices_bw(
             model, self._bounds, widths, graph, rng, train=True, loss_ct=loss_ct,
@@ -917,7 +933,8 @@ class CompiledGNNPipeline(PipelineEngine):
         def fwd(s):
             def branch(operand):
                 chunk, h_in, _ct, _w = operand
-                y = slices[s](params, chunk, h_in)
+                with jax.named_scope(f"pipe.fwd.s{s}"):
+                    y = slices[s](params, chunk, h_in)
                 return y, zero_wire, zero_wres, zeros_grads(), zero, zero
 
             return branch
@@ -931,12 +948,13 @@ class CompiledGNNPipeline(PipelineEngine):
                 def f(p, h):
                     return slices[s](p, chunk, h)
 
-                y, vjp = jax.vjp(f, params, h_in)
-                if last:
-                    ct, loss_sum, count = loss_ct(y, chunk)
-                else:
-                    loss_sum = count = zero
-                d_params, d_h = vjp(ct)
+                with jax.named_scope(f"pipe.bwd.s{s}"):
+                    y, vjp = jax.vjp(f, params, h_in)
+                    if last:
+                        ct, loss_sum, count = loss_ct(y, chunk)
+                    else:
+                        loss_sum = count = zero
+                    d_params, d_h = vjp(ct)
                 return zero_wire, d_h, zero_wres, d_params, loss_sum, count
 
             return branch
@@ -944,7 +962,8 @@ class CompiledGNNPipeline(PipelineEngine):
         def bwd_b(s):
             def branch(operand):
                 chunk, h_in, ct, _w = operand
-                d_h, w_out, loss_sum, count = b_fns[s](params, chunk, h_in, ct)
+                with jax.named_scope(f"pipe.bwd_b.s{s}"):
+                    d_h, w_out, loss_sum, count = b_fns[s](params, chunk, h_in, ct)
                 return zero_wire, d_h, w_out, zeros_grads(), loss_sum, count
 
             return branch
@@ -952,7 +971,8 @@ class CompiledGNNPipeline(PipelineEngine):
         def bwd_w(s):
             def branch(operand):
                 chunk, _h, _ct, w_res = operand
-                d_params = w_fns[s](params, chunk, w_res)
+                with jax.named_scope(f"pipe.bwd_w.s{s}"):
+                    d_params = w_fns[s](params, chunk, w_res)
                 return zero_wire, zero_wire, zero_wres, d_params, zero, zero
 
             return branch
@@ -1089,10 +1109,11 @@ class CompiledGNNPipeline(PipelineEngine):
         def step(params, opt_state, graph, labels, loss_mask, rng):
             m = loss_mask.astype(jnp.float32)
             grads, loss_sum, count = mapped(params, graph, labels, m, rng)
-            scale = 1.0 / jnp.maximum(count, 1.0)
-            grads = jax.tree_util.tree_map(lambda g: g * scale, grads)
-            updates, opt_state = optimizer.update(grads, opt_state, params)
-            params = opt_lib.apply_updates(params, updates)
+            with jax.named_scope("pipe.optimizer"):
+                scale = 1.0 / jnp.maximum(count, 1.0)
+                grads = jax.tree_util.tree_map(lambda g: g * scale, grads)
+                updates, opt_state = optimizer.update(grads, opt_state, params)
+                params = opt_lib.apply_updates(params, updates)
             return params, opt_state, loss_sum / jnp.maximum(count, 1.0)
 
         return jax.jit(step)
@@ -1136,7 +1157,8 @@ class CompiledGNNPipeline(PipelineEngine):
             def fwd(s):
                 def branch(operand):
                     chunk, h_in = operand
-                    return slices[s](params, chunk, h_in)
+                    with jax.named_scope(f"pipe.fwd.s{s}"):
+                        return slices[s](params, chunk, h_in)
 
                 return branch
 

@@ -74,6 +74,14 @@ descending-chunk order (gathered over the optional ``data_axis`` first, in
 descending replica order), then ``psum`` over the stage ring — which is why
 every schedule, placement, and data-parallel width produces bit-identical
 updates.
+
+Named scopes: each executor runs under ``jax.named_scope("pipe.exec")`` —
+stash banking, slot picks, gradient-slot accumulation, the work dispatch and
+the drain reduction — and its collectives (ring hops, the data-axis gather,
+the closing psums) under ``"pipe.wire"``. The stage work a ``work_fn`` or
+``stage_fn`` brings nests its own scopes inside, so a device profile reads
+executor overhead as the ops whose innermost ``pipe.*`` scope is one of
+these two.
 """
 
 from __future__ import annotations
@@ -85,6 +93,13 @@ import jax.numpy as jnp
 from jax import lax
 
 
+def _wire(collective, *args, **kwargs):
+    """``collective(*args, **kwargs)`` under the ``pipe.wire`` scope."""
+    with jax.named_scope("pipe.wire"):
+        return collective(*args, **kwargs)
+
+
+@jax.named_scope("pipe.exec")
 def spmd_pipeline(
     stage_fn: Callable[[Any, Any], tuple[Any, Any]],
     x: Any,
@@ -148,8 +163,9 @@ def spmd_pipeline(
             st_mb_new,
         )
 
-        nxt = lax.ppermute(
-            y, stage_axis, perm=[(i, (i + 1) % num_stages) for i in range(num_stages)]
+        nxt = _wire(
+            lax.ppermute, y, stage_axis,
+            perm=[(i, (i + 1) % num_stages) for i in range(num_stages)],
         )
         # y is emitted as a scan output (ys), NOT carried in an accumulator:
         # a carried buffer would be saved per tick as an AD residual
@@ -180,17 +196,18 @@ def spmd_pipeline(
     if reduce == "none":
         return outputs, state
     if scatter_dim is None:
-        outputs = lax.psum(outputs, stage_axis)
+        outputs = _wire(lax.psum, outputs, stage_axis)
     else:
         outputs = tree_map(
-            lambda a: lax.psum_scatter(
-                a, stage_axis, scatter_dimension=scatter_dim, tiled=True
+            lambda a: _wire(
+                lax.psum_scatter, a, stage_axis, scatter_dimension=scatter_dim, tiled=True
             ),
             outputs,
         )
     return outputs, state
 
 
+@jax.named_scope("pipe.exec")
 def spmd_pipeline_interleaved(
     stage_fn: Callable[[jax.Array, Any], Any],
     x: jax.Array,
@@ -255,9 +272,7 @@ def spmd_pipeline_interleaved(
         my_in = jnp.where(first_round, fresh, stored)
         y = fn(v, my_in)
 
-        nxt = lax.ppermute(
-            y, stage_axis, perm=[(i, (i + 1) % D) for i in range(D)]
-        )
+        nxt = _wire(lax.ppermute, y, stage_axis, perm=[(i, (i + 1) % D) for i in range(D)])
         return (nxt, buf), y
 
     prev0 = match_vma(jnp.zeros_like(x[0]), x, vma_refs, extra=(stage_axis,))
@@ -269,9 +284,10 @@ def spmd_pipeline_interleaved(
     # device D-1 runs (v = V-1, chunk c) at tick (V-1)·C + c + D - 1
     outputs = ys[(V - 1) * C + D - 1 :]
     outputs = jnp.where(is_last, outputs, jnp.zeros_like(outputs))
-    return lax.psum(outputs, stage_axis)
+    return _wire(lax.psum, outputs, stage_axis)
 
 
+@jax.named_scope("pipe.exec")
 def spmd_pipeline_scheduled(
     work_fn: Callable[..., tuple],
     lowered,
@@ -405,8 +421,8 @@ def spmd_pipeline_scheduled(
             # post tick t+1's arrivals (tick t-1's outputs, parked in the
             # pending buffers) before this tick's work: no value below reads
             # next_f/next_b, so XLA may run the collective under the compute
-            next_f = lax.ppermute(wires[2], stage_axis, perm=fwd_perm)
-            next_b = lax.ppermute(wires[3], stage_axis, perm=bwd_perm)
+            next_f = _wire(lax.ppermute, wires[2], stage_axis, perm=fwd_perm)
+            next_b = _wire(lax.ppermute, wires[3], stage_axis, perm=bwd_perm)
         h_in = lax.dynamic_index_in_dim(fstash, pick("work_fslot", t), 0, keepdims=False)
         ct_in = lax.dynamic_index_in_dim(bstash, pick("work_bslot", t), 0, keepdims=False)
         # fused-backward schedules allocate no residual slots; skip the
@@ -445,8 +461,8 @@ def spmd_pipeline_scheduled(
             wires = (next_f, next_b, y, d_h)
         else:
             wires = (
-                lax.ppermute(y, stage_axis, perm=fwd_perm),
-                lax.ppermute(d_h, stage_axis, perm=bwd_perm),
+                _wire(lax.ppermute, y, stage_axis, perm=fwd_perm),
+                _wire(lax.ppermute, d_h, stage_axis, perm=bwd_perm),
             )
         return (
             wires, fstash, bstash, wstash, gbuf,
@@ -481,7 +497,8 @@ def spmd_pipeline_scheduled(
             # has one nonzero addend, so the sum is exact, and the result is
             # invariant over the data axis, as a replicated output must be
             slots = jnp.zeros((dp,) + x.shape, x.dtype)
-            return lax.psum(lax.dynamic_update_index_in_dim(slots, x, r_self, 0), data_axis)
+            slots = lax.dynamic_update_index_in_dim(slots, x, r_self, 0)
+            return _wire(lax.psum, slots, data_axis)
 
         gall = tree_map(gather, gbuf)
         for r in reversed(range(dp)):
@@ -489,12 +506,13 @@ def spmd_pipeline_scheduled(
                 grads = tree_map(lambda g, b, r=r, c=c: g + b[r, c], grads, gall)
         loss = jnp.sum(gather(loss))
         count = jnp.sum(gather(count))
-    grads = lax.psum(grads, stage_axis)
-    loss = lax.psum(loss, stage_axis)
-    count = lax.psum(count, stage_axis)
+    grads = _wire(lax.psum, grads, stage_axis)
+    loss = _wire(lax.psum, loss, stage_axis)
+    count = _wire(lax.psum, count, stage_axis)
     return grads, loss, count
 
 
+@jax.named_scope("pipe.exec")
 def spmd_pipeline_scheduled_lanes(
     work_fn: Callable[..., tuple],
     lowered,
@@ -660,6 +678,7 @@ def _eval_out_slot(lowered):
     return np.where(last, lowered.chunk, lowered.num_chunks).astype(np.int32)
 
 
+@jax.named_scope("pipe.exec")
 def spmd_pipeline_scheduled_eval(
     work_fn: Callable[..., jax.Array],
     lowered,
@@ -706,14 +725,15 @@ def spmd_pipeline_scheduled_eval(
         h_in = lax.dynamic_index_in_dim(fstash, pick("work_fslot", t), 0, keepdims=False)
         y = work_fn(pick("phase", t), pick("stage", t), pick("chunk", t), h_in)
         out = lax.dynamic_update_index_in_dim(out, y, pick("out_slot", t), 0)
-        wire_f = lax.ppermute(y, stage_axis, perm=fwd_perm)
+        wire_f = _wire(lax.ppermute, y, stage_axis, perm=fwd_perm)
         return (wire_f, fstash, out), None
 
     carry0 = match_vma((zero_wire, fstash0, out0), vma_refs, extra=(stage_axis,))
     (_, _, out), _ = lax.scan(tick_body, carry0, jnp.arange(T))
-    return lax.psum(out[:C], stage_axis)
+    return _wire(lax.psum, out[:C], stage_axis)
 
 
+@jax.named_scope("pipe.exec")
 def spmd_pipeline_scheduled_eval_lanes(
     work_fn: Callable[..., jax.Array],
     lowered,

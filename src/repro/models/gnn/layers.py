@@ -13,6 +13,12 @@ apply function. Three aggregation backends exist:
 The GAT layer follows the paper §2.1 / Veličković et al. exactly:
 ``alpha_ij ∝ exp(LeakyReLU(a^T [Wh_i || Wh_j]))`` with multi-head concat or
 average, attention dropout, masked softmax over the neighborhood.
+
+Every layer puts its feature matmuls under ``jax.named_scope("gnn.transform")``
+and its neighbourhood work (gather, scores, softmax, weighted sum or kernel
+call, bias) under ``"gnn.agg"``. The names reach the compiled program's op
+metadata, so a device profile tells aggregation from transform; backward ops
+carry ``transpose(jvp(...))`` around the same names.
 """
 
 from __future__ import annotations
@@ -71,23 +77,25 @@ def init_gcn(key: jax.Array, in_dim: int, out_dim: int) -> dict:
 
 def gcn_layer(params: dict, g: GraphBatch, h: jax.Array, *, backend: str = "padded") -> jax.Array:
     """H' = Â H W + b with symmetric normalization (Kipf & Welling)."""
-    hw = h @ params["w"]
-    if backend == "dense":
-        agg = _dense_norm(g) @ hw
-    elif backend == "pallas":
-        if isinstance(g, BucketedGraphBatch):
-            from repro.kernels.spmm.ops import bucketed_spmm
+    with jax.named_scope("gnn.transform"):
+        hw = h @ params["w"]
+    with jax.named_scope("gnn.agg"):
+        if backend == "dense":
+            agg = _dense_norm(g) @ hw
+        elif backend == "pallas":
+            if isinstance(g, BucketedGraphBatch):
+                from repro.kernels.spmm.ops import bucketed_spmm
 
-            nbrs, nrms, _, _ = _bucket_fields(g)
-            agg = bucketed_spmm(hw, nbrs, nrms, g.gather_rows)
+                nbrs, nrms, _, _ = _bucket_fields(g)
+                agg = bucketed_spmm(hw, nbrs, nrms, g.gather_rows)
+            else:
+                from repro.kernels.spmm.ops import padded_spmm
+
+                agg = padded_spmm(hw, g.neighbors, g.norm)
         else:
-            from repro.kernels.spmm.ops import padded_spmm
-
-            agg = padded_spmm(hw, g.neighbors, g.norm)
-    else:
-        gathered = hw[g.neighbors]  # (n, max_deg, out)
-        agg = jnp.einsum("nd,ndo->no", g.norm, gathered)
-    return agg + params["b"]
+            gathered = hw[g.neighbors]  # (n, max_deg, out)
+            agg = jnp.einsum("nd,ndo->no", g.norm, gathered)
+        return agg + params["b"]
 
 
 # ---------------------------------------------------------------- GAT ----
@@ -127,47 +135,48 @@ def gat_layer(
             "attn_dropout=0.0 or use the 'padded'/'dense' backend"
         )
     heads, _, out_dim = params["w"].shape
-    hw = jnp.einsum("nf,hfo->nho", h, params["w"])  # (n, H, F')
-    s_src = jnp.einsum("nho,ho->nh", hw, params["a_src"])  # importance of i as dst
-    s_dst = jnp.einsum("nho,ho->nh", hw, params["a_dst"])  # importance of j as src
+    with jax.named_scope("gnn.transform"):
+        hw = jnp.einsum("nf,hfo->nho", h, params["w"])  # (n, H, F')
+        s_src = jnp.einsum("nho,ho->nh", hw, params["a_src"])  # importance of i as dst
+        s_dst = jnp.einsum("nho,ho->nh", hw, params["a_dst"])  # importance of j as src
+    with jax.named_scope("gnn.agg"):
+        if backend == "pallas":
+            if isinstance(g, BucketedGraphBatch):
+                from repro.kernels.gat_edge.ops import bucketed_gat_aggregate
 
-    if backend == "pallas":
-        if isinstance(g, BucketedGraphBatch):
-            from repro.kernels.gat_edge.ops import bucketed_gat_aggregate
+                nbrs, _, msks, rows = _bucket_fields(g)
+                out = bucketed_gat_aggregate(
+                    hw, s_src, s_dst, nbrs, msks, rows, g.gather_rows,
+                    negative_slope,
+                )
+            else:
+                from repro.kernels.gat_edge.ops import gat_aggregate
 
-            nbrs, _, msks, rows = _bucket_fields(g)
-            out = bucketed_gat_aggregate(
-                hw, s_src, s_dst, nbrs, msks, rows, g.gather_rows,
-                negative_slope,
-            )
+                out = gat_aggregate(
+                    hw, s_src, s_dst, g.neighbors, g.mask, negative_slope=negative_slope
+                )
+        elif backend == "dense":
+            adj = _dense_adj(g)  # (n, n)
+            scores = s_src[:, None, :] + s_dst[None, :, :]  # (n, n, H)
+            scores = jax.nn.leaky_relu(scores, negative_slope)
+            scores = jnp.where(adj[..., None], scores, _NEG_INF)
+            alpha = jax.nn.softmax(scores, axis=1)
+            alpha = alpha * adj[..., None]
+            alpha = dropout(alpha, attn_dropout, rng, train)
+            out = jnp.einsum("njh,jho->nho", alpha, hw)
         else:
-            from repro.kernels.gat_edge.ops import gat_aggregate
+            nbr_scores = s_dst[g.neighbors]  # (n, max_deg, H)
+            scores = jax.nn.leaky_relu(s_src[:, None, :] + nbr_scores, negative_slope)
+            scores = jnp.where(g.mask[..., None], scores, _NEG_INF)
+            alpha = jax.nn.softmax(scores, axis=1)
+            alpha = alpha * g.mask[..., None]  # zero out fully-padded rows
+            alpha = dropout(alpha, attn_dropout, rng, train)
+            out = jnp.einsum("ndh,ndho->nho", alpha, hw[g.neighbors])
 
-            out = gat_aggregate(
-                hw, s_src, s_dst, g.neighbors, g.mask, negative_slope=negative_slope
-            )
-    elif backend == "dense":
-        adj = _dense_adj(g)  # (n, n)
-        scores = s_src[:, None, :] + s_dst[None, :, :]  # (n, n, H)
-        scores = jax.nn.leaky_relu(scores, negative_slope)
-        scores = jnp.where(adj[..., None], scores, _NEG_INF)
-        alpha = jax.nn.softmax(scores, axis=1)
-        alpha = alpha * adj[..., None]
-        alpha = dropout(alpha, attn_dropout, rng, train)
-        out = jnp.einsum("njh,jho->nho", alpha, hw)
-    else:
-        nbr_scores = s_dst[g.neighbors]  # (n, max_deg, H)
-        scores = jax.nn.leaky_relu(s_src[:, None, :] + nbr_scores, negative_slope)
-        scores = jnp.where(g.mask[..., None], scores, _NEG_INF)
-        alpha = jax.nn.softmax(scores, axis=1)
-        alpha = alpha * g.mask[..., None]  # zero out fully-padded rows
-        alpha = dropout(alpha, attn_dropout, rng, train)
-        out = jnp.einsum("ndh,ndho->nho", alpha, hw[g.neighbors])
-
-    out = out + params["b"]
-    if concat:
-        return out.reshape(out.shape[0], heads * out_dim)
-    return out.mean(axis=1)
+        out = out + params["b"]
+        if concat:
+            return out.reshape(out.shape[0], heads * out_dim)
+        return out.mean(axis=1)
 
 
 # ---------------------------------------------------------- GraphConv ----
@@ -184,13 +193,15 @@ def init_graph_conv(key: jax.Array, in_dim: int, out_dim: int) -> dict:
 
 def graph_conv_layer(params: dict, g: GraphBatch, h: jax.Array, *, backend: str = "padded") -> jax.Array:
     """GraphConv (Morris et al.): H' = H W1 + (A H) W2 + b (no self in A)."""
-    nbr_mask = g.mask.at[:, 0].set(False)  # slot 0 is the self-loop
-    if backend == "dense":
-        adj = _dense_adj(g) & ~jnp.eye(g.num_nodes, dtype=bool)
-        agg = adj.astype(h.dtype) @ h
-    else:
-        agg = jnp.einsum("nd,ndf->nf", nbr_mask.astype(h.dtype), h[g.neighbors])
-    return h @ params["w_self"] + agg @ params["w_nbr"] + params["b"]
+    with jax.named_scope("gnn.agg"):
+        nbr_mask = g.mask.at[:, 0].set(False)  # slot 0 is the self-loop
+        if backend == "dense":
+            adj = _dense_adj(g) & ~jnp.eye(g.num_nodes, dtype=bool)
+            agg = adj.astype(h.dtype) @ h
+        else:
+            agg = jnp.einsum("nd,ndf->nf", nbr_mask.astype(h.dtype), h[g.neighbors])
+    with jax.named_scope("gnn.transform"):
+        return h @ params["w_self"] + agg @ params["w_nbr"] + params["b"]
 
 
 # ----------------------------------------------------- GatedGraphConv ----
@@ -219,15 +230,18 @@ def gated_graph_conv_layer(
     nbr_mask = g.mask.astype(h.dtype)
 
     def step(state, _):
-        msg = state @ params["w_msg"]
-        if backend == "dense":
-            agg = _dense_adj(g).astype(h.dtype) @ msg
-        else:
-            agg = jnp.einsum("nd,ndf->nf", nbr_mask, msg[g.neighbors])
-        zr = jax.nn.sigmoid(agg @ params["w_zr"] + state @ params["u_zr"])
-        z, r = jnp.split(zr, 2, axis=-1)
-        cand = jnp.tanh(agg @ params["w_h"] + (r * state) @ params["u_h"])
-        return (1.0 - z) * state + z * cand, None
+        with jax.named_scope("gnn.transform"):
+            msg = state @ params["w_msg"]
+        with jax.named_scope("gnn.agg"):
+            if backend == "dense":
+                agg = _dense_adj(g).astype(h.dtype) @ msg
+            else:
+                agg = jnp.einsum("nd,ndf->nf", nbr_mask, msg[g.neighbors])
+        with jax.named_scope("gnn.transform"):
+            zr = jax.nn.sigmoid(agg @ params["w_zr"] + state @ params["u_zr"])
+            z, r = jnp.split(zr, 2, axis=-1)
+            cand = jnp.tanh(agg @ params["w_h"] + (r * state) @ params["u_h"])
+            return (1.0 - z) * state + z * cand, None
 
     out, _ = jax.lax.scan(step, h, None, length=steps)
     return out

@@ -191,6 +191,7 @@ def make_gnn_stage(
     def branch(s: int):
         lo, hi = bounds[s]
 
+        @jax.named_scope(f"pipe.fwd.s{s}")
         def apply_slice(operand):
             travel, rngs = operand
             c = travel["chunk"]

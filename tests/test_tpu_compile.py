@@ -3,10 +3,11 @@
 The TPU compiler installed with JAX compiles for a topology it is only
 told about. These tests compile the Pallas kernels of the main path at the
 cora stand-in's widths, and one whole lane-substrate train step of the
-paper GAT with ``--backend pallas``, for one chip of a ``v5e:2x2``. They
-catch what interpret mode cannot: blocks not aligned to the tiling, scalar
-reads Mosaic refuses, more VMEM or SMEM than a kernel may use. Nothing
-runs, so they say nothing about results or times.
+paper GAT with ``--backend pallas``, for one chip of a ``v5e:2x2``, and the
+4-stage ring's train step across its four chips. They catch what interpret
+mode cannot: blocks not aligned to the tiling, scalar reads Mosaic refuses,
+more VMEM or SMEM than a kernel may use. Nothing runs, so they say nothing
+about results or times.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every test worker
@@ -18,10 +19,12 @@ to describe the topology fails them.
 
 import importlib.util
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
 
 pytestmark = pytest.mark.xdist_group("tpu_compile")
 
@@ -32,10 +35,10 @@ HEADS, HIDDEN = 8, 8
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """One chip of a described v5e:2x2, with JAX's persistent compilation
-    cache off: a compile for a described chip is written to the cache but
-    can never be read back without one."""
+def v5e_2x2():
+    """A described v5e:2x2, with JAX's persistent compilation cache off: a
+    compile for a described chip is written to the cache but can never be
+    read back without one."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -48,9 +51,15 @@ def one_chip():
         jax.config.update("jax_enable_compilation_cache", False)
         compilation_cache.reset_cache()
         try:
-            yield SingleDeviceSharding(topo.devices[0])
+            yield topo
         finally:
             jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    """One chip of the described v5e:2x2."""
+    return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
 def _compile(fn, one_chip, *shapes):
@@ -146,3 +155,40 @@ def test_pallas_lane_train_step_compiles(one_chip, monkeypatch):
     compiled = step.lower(*abstract).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * 1024**3  # v5e HBM
+
+
+def test_ring_step_hops_sit_under_the_wire_scope(v5e_2x2, monkeypatch):
+    """The 4-stage ring's 1F1B train step, one stage per chip of the
+    v5e:2x2 (a small GCN on karate): the compiler keeps every ring hop
+    (``collective-permute``) under the executor's ``pipe.wire`` scope, so a
+    device profile reads the ring's collectives by name. The engine builds
+    its mesh from ``jax.devices()``, so the test hands it the described
+    chips."""
+    from repro.core.microbatch import make_plan
+    from repro.core.pipeline import GPipeConfig, make_engine
+    from repro.graphs import load_dataset
+    from repro.models.gnn.net import build_gnn
+    from repro.train import optimizer as opt_lib
+
+    chips = list(v5e_2x2.devices)
+    g = load_dataset("karate")
+    model = build_gnn("gcn", g.num_features, g.num_classes, hidden=16, depth=4)
+    engine = make_engine(model, GPipeConfig(
+        balance=(2, 2, 2, 2), chunks=4, schedule="1f1b", engine="compiled"))
+    plan = make_plan(g, 4, strategy="halo", halo_hops=1)
+    optimizer = opt_lib.adam(1e-2)
+    params = engine.init_params(jax.random.PRNGKey(0))
+    monkeypatch.setattr(jax, "devices", lambda *a: chips)
+    monkeypatch.setattr(jax, "device_count", lambda *a: len(chips))
+    step, args = engine.step_program(
+        params, optimizer.init(params), plan, jax.random.PRNGKey(1), optimizer)
+    replicated = NamedSharding(Mesh(chips, ("stage",)), PartitionSpec())
+    abstract = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=replicated), args)
+    text = step.lower(*abstract).compile().as_text()
+    hops = [line for line in text.splitlines()
+            if re.search(r" collective-permute(?:-start|-done)?\(", line)]
+    assert len(hops) >= 2  # the activation hop and the cotangent hop
+    for hop in hops:
+        found = re.search(r'op_name="([^"]*)"', hop)
+        assert found and "pipe.wire" in found.group(1).split("/"), hop[:200]
