@@ -26,13 +26,14 @@ regressed:
     comparison is run-internal, like the zero-bubble gate, so machine speed
     cancels). Missing or zero host fill-drain normalizer rows fail with a
     named-row error instead of silently shrinking the comparison set;
-  * **sparse** — the degree-bucketed pallas backend's compiled step on the
-    power-law fixture must beat the padded layout's STRICTLY in the same
-    run (``sparse/{padded|bucketed}/chunksC`` rows — run-internal, so
-    machine speed cancels), and BOTH rows must report ``updates_match``:
-    fig3 asserts each measured config's one-step update against a host
-    fill-drain padded reference at oracle tolerance, so a layout that got
-    fast by computing something else fails here, not in prod;
+  * **sparse** — the pallas and padded backends' compiled steps on the
+    power-law fixture (``sparse/{padded|bucketed}/chunksC`` rows; both
+    aggregate the GCN over the degree buckets) must both be present and
+    report ``updates_match``: fig3 asserts each measured config's one-step
+    update against a host fill-drain dense reference, which never buckets,
+    at oracle tolerance, so a bucketed path that computes something else
+    fails here, not in prod. The layout's speed against the plain padded
+    layout is the kernels gate's (``check_kernels``);
   * **scale** — the streamed-graph growth rows (``scale/nN/chunksC``, from
     fig3's ``_scale_bench``): every row's one-step update must have matched
     the host fill-drain oracle in the same run that was timed
@@ -261,11 +262,12 @@ def check(baseline: dict, current: dict, *, threshold: float, absolute: bool,
                 f"(balance {row.get('balance')} vs {uni.get('balance')})"
             )
 
-    # sparse gate: the degree-bucketed pallas backend must beat the padded
-    # layout strictly in the same run, and both measured configs' one-step
-    # updates must have matched the host fill-drain padded reference at
-    # oracle tolerance (fig3 computes updates_match in the SAME run it
-    # times, so speed can never be bought with wrong math unnoticed)
+    # sparse gate: both measured configs' one-step updates must have
+    # matched the host fill-drain dense reference at oracle tolerance (fig3
+    # computes updates_match in the SAME run it times, so speed can never
+    # be bought with wrong math unnoticed). No step race: both backends
+    # bucket this fixture, so the rows run one program on the CPU; the
+    # kernels gate times the bucketed op against the plain padded one
     for key, row in sorted(c_rows.items()):
         if not key.startswith("sparse/bucketed/"):
             continue
@@ -273,11 +275,6 @@ def check(baseline: dict, current: dict, *, threshold: float, absolute: bool,
         if pad is None:
             failures.append(f"sparse: {key} has no padded row to compare")
             continue
-        if not row["step_s"] < pad["step_s"]:
-            failures.append(
-                f"sparse: {key} step {row['step_s']:.4f}s not strictly below "
-                f"the padded layout's {pad['step_s']:.4f}s"
-            )
         for name, r in (("bucketed", row), ("padded", pad)):
             if not r.get("updates_match"):
                 failures.append(

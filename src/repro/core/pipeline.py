@@ -58,7 +58,7 @@ from jax.sharding import PartitionSpec as P
 from repro.core.vma import match_vma
 from repro.core.microbatch import MicroBatchPlan
 from repro.graphs.data import BucketedGraphBatch
-from repro.graphs.partition import bucketize_stacked
+from repro.graphs.partition import bucketize_stacked, layout_slots
 from repro.core.schedule import (
     PHASE_BWD,
     PHASE_BWD_B,
@@ -106,10 +106,13 @@ class GPipeConfig:
     placement: Placement | None = None
     engine: str = "host"  # "host" | "compiled"; consumed by make_engine
     # aggregation backend: "padded" | "dense" | "pallas". Must match the
-    # backend the model's layers were built with; under "pallas" both
-    # engines additionally feed the stage programs the degree-bucketed
-    # layout (graphs.partition.bucketize_stacked) instead of the raw
-    # padded batch, so aggregation work tracks the degree distribution.
+    # backend the model's layers were built with; under "pallas", and under
+    # "padded" when the buckets hold fewer slots than the padded layout
+    # (which a one-rung ladder, max_deg <= 8, never does), both engines feed the
+    # stage programs the degree-bucketed layout
+    # (graphs.partition.bucketize_stacked) wrapped around the padded batch,
+    # so GCN aggregation work tracks the degree distribution (the padded
+    # GAT still reads the padded batch through the wrapper).
     backend: str = "padded"
     # graph data parallelism (compiled engine): replicas on the "data" axis
     # of a (data, stage) mesh, each running the pipeline over its contiguous
@@ -268,6 +271,9 @@ class PipelineEngine:
         # graph -> backend layout, keyed by id(); entries retain the graph
         # so a recycled id() can never serve a stale layout
         self._layout_cache: dict = {}
+        # slots of the last laid-out aggregation layout over its padded
+        # batch's; describe() reports it
+        self._agg_slot_share: float | None = None
 
     # ------------------------------------------------------------ stages --
 
@@ -314,19 +320,31 @@ class PipelineEngine:
 
     def layout(self, graph):
         """The aggregation layout this engine's programs consume for a
-        chunk-stacked ``graph``: the padded batch itself for the padded and
-        dense backends, its degree-bucketed wrapper
-        (``graphs.partition.bucketize_stacked``) under ``backend="pallas"``.
-        The wrapper delegates every padded-batch attribute, so downstream
-        plumbing (loss masks, metric heads, shape keys) is layout-blind."""
-        if self.config.backend != "pallas" or isinstance(graph, BucketedGraphBatch):
+        chunk-stacked ``graph``: its degree-bucketed wrapper
+        (``graphs.partition.bucketize_stacked``) under ``"pallas"``, and
+        under ``"padded"`` where the buckets hold fewer slots than the
+        padded layout (never for a one-rung ladder, ``max_deg <= 8``, whose
+        one bucket is the padded layout rounded up); else the padded batch
+        itself. The wrapper delegates every padded-batch attribute, so
+        downstream plumbing (loss masks, metric heads, shape keys) is
+        layout-blind. Records the layout's ``agg_slot_share`` for
+        ``describe()``."""
+        if isinstance(graph, BucketedGraphBatch):
+            self._agg_slot_share = layout_slots(graph) / layout_slots(graph.base)
+            return graph
+        if self.config.backend not in ("padded", "pallas"):
+            self._agg_slot_share = 1.0
             return graph
         cached = self._layout_cache.get(id(graph))
         if cached is not None and cached[0] is graph:
-            return cached[1]
-        wrapped = bucketize_stacked(graph)
-        self._layout_cache[id(graph)] = (graph, wrapped)
-        return wrapped
+            out = cached[1]
+        else:
+            out = bucketize_stacked(graph)
+            if self.config.backend == "padded" and layout_slots(out) >= layout_slots(graph):
+                out = graph  # rounding each bucket's rows up adds more than it drops
+            self._layout_cache[id(graph)] = (graph, out)
+        self._agg_slot_share = layout_slots(out) / layout_slots(graph)
+        return out
 
     def evaluate(self, params: list, plan: MicroBatchPlan) -> dict:
         """Forward-only inference over the plan's chunks: the same metric
@@ -349,6 +367,9 @@ class PipelineEngine:
                 "balance": list(self.config.balance),
                 "chunks": self.config.chunks,
                 "layers": [l.name for l in self.model.layers],
+                # the aggregation layout's slots over the padded layout's,
+                # for the last graph laid out (None before the first)
+                "agg_slot_share": self._agg_slot_share,
             }
         )
         if self.placement is not None:
@@ -493,24 +514,27 @@ class GPipe(PipelineEngine):
         chunk_key = jax.random.fold_in(rng, chunk)
         return jax.random.split(chunk_key, n_layers)
 
-    def _chunk_graphs(self, plan: MicroBatchPlan) -> list:
-        """Per-chunk graphs the stage fns consume: the plan's padded batches
-        as-is, or (pallas) their degree-bucketed layouts. All chunks share
-        one set of bucket capacities (``bucketize_stacked`` on the stacked
-        plan, sliced back per chunk), so each per-stage jitted fn compiles
-        once and serves every chunk."""
-        if self.config.backend != "pallas":
-            return [mb.graph for mb in plan.batches]
+    def _chunk_graphs(self, plan: MicroBatchPlan) -> tuple[list, list]:
+        """Per-chunk graphs the stage fns consume and their core masks: the
+        plan's padded batches as-is, or (where ``layout`` buckets) their
+        degree-bucketed layouts. All chunks share one set of bucket
+        capacities (``bucketize_stacked`` on the stacked plan, sliced back
+        per chunk, with the stacked plan's core masks), so each per-stage
+        jitted fn compiles once and serves every chunk."""
+        stacked = plan.stacked()
+        wrapped = self.layout(stacked.graph)
+        if not isinstance(wrapped, BucketedGraphBatch):
+            return [mb.graph for mb in plan.batches], [mb.core_mask for mb in plan.batches]
         cached = self._layout_cache.get(id(plan))
         if cached is not None and cached[0] is plan:
             return cached[1]
-        stacked = self.layout(plan.stacked().graph)
         graphs = [
-            jax.tree_util.tree_map(lambda a, c=c: a[c], stacked)
+            jax.tree_util.tree_map(lambda a, c=c: a[c], wrapped)
             for c in range(plan.chunks)
         ]
-        self._layout_cache[id(plan)] = (plan, graphs)
-        return graphs
+        cores = [stacked.core_mask[c] for c in range(plan.chunks)]
+        self._layout_cache[id(plan)] = (plan, (graphs, cores))
+        return graphs, cores
 
     def _run_fwd_item(self, params, plan, graphs, rng, it, saved, outs, record):
         """Execute one forward work item: consume the saved stage input,
@@ -561,7 +585,7 @@ class GPipe(PipelineEngine):
             # re-device the items (ticks/order untouched): recorded items and
             # _place() then reflect the configured stage->device assignment
             timeline = self.placement.apply(timeline)
-        graphs = self._chunk_graphs(plan)
+        graphs, cores = self._chunk_graphs(plan)
 
         saved: dict[tuple[int, int], Any] = {}
         outs: dict[int, Any] = {}
@@ -578,12 +602,11 @@ class GPipe(PipelineEngine):
                 peak_live = max(peak_live, len(saved))
                 continue
             s, c = it.stage, it.chunk
-            mb = plan.batches[c]
             g = graphs[c]
             if s == S - 1 and it.phase in ("bwd", "bwd_b"):
                 # the chunk's loss cotangent, computed once its fwd completes
                 (loss_sum, count), d_h = self._loss_grad(
-                    outs.pop(c), mb.graph.labels, mb.graph.train_mask & mb.core_mask
+                    outs.pop(c), g.labels, g.train_mask & cores[c]
                 )
                 chunk_losses[c] = (loss_sum, count)
                 cts[c] = d_h
@@ -645,6 +668,7 @@ class GPipe(PipelineEngine):
 
         if stats is not None:
             stats.update(self.schedule.describe(S, C))
+            stats["agg_slot_share"] = self._agg_slot_share
             stats["measured_peak_live_activations"] = peak_live
             stats["measured_peak_w_residuals"] = peak_residuals
 

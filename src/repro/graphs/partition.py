@@ -288,6 +288,15 @@ def bucketize_stacked(
     return BucketedGraphBatch(base=g, buckets=stacked_buckets, gather_rows=gather)
 
 
+def layout_slots(graph) -> int:
+    """Neighbor slots the aggregation layout materializes per chunk:
+    ``n_pad · max_deg`` for the padded layout, ``Σ rows_b · width_b`` for a
+    degree-bucketed wrapper."""
+    if isinstance(graph, BucketedGraphBatch):
+        return int(sum(b.rows * b.width for b in graph.buckets))
+    return int(graph.neighbors.shape[-2] * graph.neighbors.shape[-1])
+
+
 def edge_cut_fraction(g: GraphBatch, parts: list[np.ndarray]) -> float:
     """Fraction of (directed, non-self) edge slots crossing part boundaries —
     the information the paper's sequential split throws away."""

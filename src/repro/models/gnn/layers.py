@@ -4,7 +4,8 @@ Every layer comes as an ``init_*`` (params pytree) plus a pure ``*_layer``
 apply function. Three aggregation backends exist:
 
   * ``padded`` — gather neighbors along the (n, max_deg) layout; the
-    TPU-native default.
+    TPU-native default. GCN handed a ``BucketedGraphBatch`` gathers over
+    its degree-bucketed tiles instead, in the same plain XLA ops.
   * ``dense``  — materialize a masked (n, n) adjacency and matmul; only for
     small graphs, used by benchmarks as the "second framework" analogue of
     the paper's DGL-vs-PyG comparison.
@@ -27,6 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.graphs.data import BucketedGraphBatch, GraphBatch
+from repro.kernels.spmm.ref import bucketed_spmm_ref
 
 _NEG_INF = -1e9
 
@@ -92,6 +94,12 @@ def gcn_layer(params: dict, g: GraphBatch, h: jax.Array, *, backend: str = "padd
                 from repro.kernels.spmm.ops import padded_spmm
 
                 agg = padded_spmm(hw, g.neighbors, g.norm)
+        elif isinstance(g, BucketedGraphBatch):
+            # the same weighted gather over the degree buckets, which leave
+            # most padding slots (exact zeros) out of the gather and out of
+            # its transposed scatter-add
+            nbrs, nrms, _, _ = _bucket_fields(g)
+            agg = bucketed_spmm_ref(hw, nbrs, nrms, g.gather_rows)
         else:
             gathered = hw[g.neighbors]  # (n, max_deg, out)
             agg = jnp.einsum("nd,ndo->no", g.norm, gathered)

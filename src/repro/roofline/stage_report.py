@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.graphs.data import BucketedGraphBatch
+from repro.graphs.partition import layout_slots
 from repro.models.gnn.net import (
     GNNModel,
     activation_widths,
@@ -31,15 +31,6 @@ from repro.models.gnn.net import (
 from repro.roofline.hlo_walk import analyze_hlo
 
 _F32 = 4  # bytes; the framework's layers run f32
-
-
-def layout_slots(graph) -> int:
-    """Neighbor slots the aggregation layout materializes per chunk:
-    ``n_pad · max_deg`` for the padded layout, ``Σ rows_b · width_b`` for a
-    degree-bucketed wrapper."""
-    if isinstance(graph, BucketedGraphBatch):
-        return int(sum(b.rows * b.width for b in graph.buckets))
-    return int(graph.neighbors.shape[-2] * graph.neighbors.shape[-1])
 
 
 def live_slots(graph) -> float:

@@ -285,12 +285,17 @@ def _sparse_rows(padded, bucketed, *, padded_match=True, bucketed_match=True):
     return rows
 
 
-def test_sparse_gate_requires_strict_bucketed_win():
+def test_sparse_gate_leaves_the_step_race_to_the_kernels_gate():
+    """Both backends bucket the fixture, so the engine rows may tie either
+    way; only their updates are gated."""
     good = _table(**_sparse_rows(0.35, 0.05))
     assert check(good, good, threshold=1.2, absolute=False) == []
-    tie = _table(**_sparse_rows(0.35, 0.35))
-    failures = check(good, tie, threshold=1.2, absolute=False)
-    assert any(f.startswith("sparse:") and "not strictly below" in f for f in failures)
+    for padded, bucketed in ((0.35, 0.35), (0.35, 0.36)):
+        cur = _table(**_sparse_rows(padded, bucketed))
+        assert not any(
+            f.startswith("sparse:")
+            for f in check(good, cur, threshold=1.2, absolute=False)
+        ), (padded, bucketed)
 
 
 def test_sparse_gate_requires_updates_match_on_both_rows():

@@ -1027,11 +1027,14 @@ BACKEND_MATRIX = [  # (engine, schedule): fused scan + split-B/W executor
 
 
 def _backend_fixture(dataset):
-    """(graph, model-factory, balance) for the backend-equivalence matrix.
+    """(graph, model-factory, balance, reference backend) for the
+    backend-equivalence matrix.
 
     karate drives the paper GAT (attention path; attn_dropout=0 because the
     fused kernel is deterministic), the skewed twin drives a GCN whose padded
-    layout is mostly padding — the case the bucketed layout exists for."""
+    layout is mostly padding — the case the bucketed layout exists for. The
+    reference backend is one that never reads the buckets: the padded GAT,
+    and the dense GCN (the padded GCN buckets skewed-mini itself)."""
     from repro.models.gnn.net import build_gnn
 
     if dataset == "karate":
@@ -1039,68 +1042,173 @@ def _backend_fixture(dataset):
         def mk(backend):
             return build_paper_gat(g.num_features, g.num_classes,
                                    backend=backend, attn_dropout=0.0)
-        return g, mk, (3, 3)
+        return g, mk, (3, 3), "padded"
     g = load_dataset("skewed-mini")
     def mk(backend):
         return build_gnn("gcn", g.num_features, g.num_classes,
                          hidden=16, depth=2, backend=backend)
-    return g, mk, (2, 2)
+    return g, mk, (2, 2), "dense"
+
+
+def _assert_matches_reference(ref, eng, plan, opt, *, steps=2, loss_tol=1e-4,
+                              param_tol=5e-4):
+    """``eng`` reproduces ``ref``'s losses, parameters after ``steps``
+    steps and eval metrics from the same initial parameters and rngs;
+    returns ``eng``'s parameters and losses."""
+    params = ref.init_params(jax.random.PRNGKey(0))
+    pr = pe = params
+    orf = oe = opt.init(params)
+    key = jax.random.PRNGKey(11)
+    losses = []
+    for _ in range(steps):
+        key, rng = jax.random.split(key)
+        pr, orf, lr = ref.train_step(pr, orf, plan, rng, opt)
+        pe, oe, le = eng.train_step(pe, oe, plan, rng, opt)
+        assert abs(float(lr) - float(le)) < loss_tol, (float(lr), float(le))
+        losses.append(float(le))
+    _params_close(pr, pe, atol=param_tol)
+    ev_r, ev_e = ref.evaluate(pr, plan), eng.evaluate(pe, plan)
+    for k in ev_r:
+        assert abs(float(ev_r[k]) - float(ev_e[k])) < 1e-4, (k, ev_r[k], ev_e[k])
+    return pe, losses
 
 
 @pytest.mark.parametrize("dataset", ["karate", "skewed-mini"])
 @pytest.mark.parametrize("engine,schedule", BACKEND_MATRIX)
 def test_pallas_backend_matches_padded_host(dataset, engine, schedule):
     """backend="pallas" (degree-bucketed aggregation inside the stage
-    programs) must reproduce the host fill-drain padded baseline's losses,
-    updates and eval metrics on both engines, through the fused scan AND the
+    programs) must reproduce a host fill-drain baseline that never buckets
+    (the padded GAT on karate, the dense GCN on skewed-mini: losses, updates
+    and eval metrics) on both engines, through the fused scan AND the
     split-B/W zb-h1 executor — the layout changes where edge slots live,
     never the math (float summation order absorbed by the oracle
     tolerance)."""
-    g, mk, balance = _backend_fixture(dataset)
-    opt = opt_lib.adam(1e-2)
+    g, mk, balance, ref_backend = _backend_fixture(dataset)
     C = 2
     plan = make_plan(g, C, strategy="sequential")
-    ref = make_engine(mk("padded"), GPipeConfig(
-        engine="host", balance=balance, chunks=C, backend="padded"))
+    ref = make_engine(mk(ref_backend), GPipeConfig(
+        engine="host", balance=balance, chunks=C, backend=ref_backend))
     pal = make_engine(mk("pallas"), GPipeConfig(
         engine=engine, balance=balance, chunks=C, schedule=schedule,
         backend="pallas"))
-    params = ref.init_params(jax.random.PRNGKey(0))
-    pr = pp = params
-    orf = opl = opt.init(params)
-    key = jax.random.PRNGKey(11)
-    for _ in range(2):
-        key, rng = jax.random.split(key)
-        pr, orf, lr = ref.train_step(pr, orf, plan, rng, opt)
-        pp, opl, lp = pal.train_step(pp, opl, plan, rng, opt)
-        assert abs(float(lr) - float(lp)) < 1e-4, (dataset, engine, schedule)
-    _params_close(pr, pp, atol=5e-4)
-    ev_r, ev_p = ref.evaluate(pr, plan), pal.evaluate(pp, plan)
-    for k in ev_r:
-        assert abs(float(ev_r[k]) - float(ev_p[k])) < 1e-4, (k, ev_r[k], ev_p[k])
+    _assert_matches_reference(ref, pal, plan, opt_lib.adam(1e-2))
 
 
-def test_engine_layout_cache_and_passthrough(setup):
-    """PipelineEngine.layout: identity for non-pallas backends and for
-    already-bucketed graphs; under backend="pallas" the bucketed wrapper is
-    built once per stacked graph and cached by identity (entries retain the
-    graph, so a recycled id() can never serve a stale layout)."""
+def _one_rung_plan(chunks):
+    """A ring graph: 3 slots per row, one bucket rung (width <= 8)."""
+    import numpy as np
+
+    from repro.graphs import build_graph_batch
+
+    n = 24
+    edges = np.stack([np.arange(n), (np.arange(n) + 1) % n], axis=1)
+    g = build_graph_batch(np.eye(n, 5, dtype=np.float32), edges,
+                          np.arange(n) % 2, 2)
+    return make_plan(g, chunks, strategy="sequential")
+
+
+@pytest.mark.parametrize("backend", ["padded", "dense", "pallas"])
+def test_engine_layout_cache_and_passthrough(setup, backend):
+    """PipelineEngine.layout: the degree-bucketed wrapper under "pallas",
+    and under "padded" where the buckets hold fewer slots than the padded
+    layout (skewed-mini); identity for "dense", under "padded" for a
+    one-rung graph and for karate (both bucket into more slots than they
+    pad), and for already-bucketed graphs. The
+    layout is built once per stacked graph and cached by identity (entries
+    retain the graph, so a recycled id() can never serve a stale layout)."""
     from repro.graphs.data import BucketedGraphBatch
 
-    g, m, _ = setup
-    plan = make_plan(g, 2, strategy="sequential")
-    stacked = plan.stacked().graph
-
-    padded = make_engine(m, GPipeConfig(engine="host", balance=(3, 3), chunks=2))
-    assert padded.layout(stacked) is stacked
-
-    pal = make_engine(m, GPipeConfig(engine="host", balance=(3, 3), chunks=2,
-                                     backend="pallas"))
-    wrapped = pal.layout(stacked)
+    _, m, _ = setup
+    stacked = make_plan(load_dataset("skewed-mini"), 2, strategy="sequential").stacked().graph
+    karate = make_plan(setup[0], 2, strategy="sequential").stacked().graph
+    narrow = _one_rung_plan(2).stacked().graph
+    eng = make_engine(m, GPipeConfig(engine="host", balance=(3, 3), chunks=2,
+                                     backend=backend))
+    if backend == "dense":
+        for graph in (stacked, karate, narrow):
+            assert eng.layout(graph) is graph
+        return
+    wrapped = eng.layout(stacked)
     assert isinstance(wrapped, BucketedGraphBatch)
     assert wrapped.base is stacked
-    assert pal.layout(stacked) is wrapped  # cached by identity
-    assert pal.layout(wrapped) is wrapped  # already bucketed: pass through
+    assert eng.layout(stacked) is wrapped  # cached by identity
+    assert eng.layout(wrapped) is wrapped  # already bucketed: pass through
+    for graph in (karate, narrow):
+        if backend == "padded":
+            assert eng.layout(graph) is graph
+            assert eng.layout(graph) is graph  # the cached choice too
+        else:
+            assert isinstance(eng.layout(graph), BucketedGraphBatch)
+
+
+@pytest.mark.parametrize("engine", ["host", "compiled"])
+def test_agg_slot_share(engine):
+    """``agg_slot_share`` in ``describe()`` and in a step's stats: 1.0 where
+    the padded batch is aggregated as it is (one bucket rung), and the
+    bucketed layout's slots over the padded layout's on a skewed graph."""
+    from repro.graphs import bucketize_stacked
+    from repro.models.gnn.net import build_gnn
+
+    opt = opt_lib.adam(1e-2)
+    narrow = _one_rung_plan(2)
+    g = load_dataset("skewed-mini")
+    skewed = make_plan(g, 2, strategy="sequential")
+    stacked = skewed.stacked().graph
+    b = bucketize_stacked(stacked)
+    want = (sum(x.rows * x.width for x in b.buckets)
+            / (stacked.neighbors.shape[1] * stacked.neighbors.shape[2]))
+    assert want < 0.5
+    for plan, share in ((narrow, 1.0), (skewed, want)):
+        d = plan.batches[0].graph.num_features
+        m = build_gnn("gcn", d, 2, hidden=8, depth=2)
+        eng = make_engine(m, GPipeConfig(engine=engine, balance=(2, 2), chunks=2,
+                                         schedule="1f1b"))
+        assert eng.describe()["agg_slot_share"] is None
+        params = eng.init_params(jax.random.PRNGKey(0))
+        stats = {}
+        eng.train_step(params, opt.init(params), plan, jax.random.PRNGKey(1), opt,
+                       stats=stats)
+        assert stats["agg_slot_share"] == pytest.approx(share, rel=1e-12)
+        assert eng.describe()["agg_slot_share"] == pytest.approx(share, rel=1e-12)
+
+
+@pytest.mark.parametrize("kind", ["gcn", "gat"])
+def test_padded_bucketed_compiled_matches_host(kind):
+    """backend="padded" over the degree-bucketed layout of skewed-mini: the
+    compiled 1F1B engine reproduces the host engine's fill-drain losses and
+    parameters over 2 steps (GCN reads the buckets; the GAT reads the
+    padded batch through the wrapper), and both reproduce a host reference
+    that never buckets (the dense GCN; the GAT's padded path) in losses,
+    parameters and eval metrics."""
+    from repro.graphs.data import BucketedGraphBatch
+    from repro.models.gnn.net import build_gnn
+
+    g = load_dataset("skewed-mini")
+    balance = (2, 2) if kind == "gcn" else (3, 3)
+    ref_backend = "dense" if kind == "gcn" else "padded"
+
+    def mk(backend):
+        if kind == "gcn":
+            return build_gnn("gcn", g.num_features, g.num_classes,
+                             hidden=16, depth=2, backend=backend)
+        return build_paper_gat(g.num_features, g.num_classes, backend=backend)
+
+    opt = opt_lib.adam(1e-2)
+    C = 2
+    plan = make_plan(g, C, strategy="sequential")
+    ref = make_engine(mk(ref_backend), GPipeConfig(
+        engine="host", balance=balance, chunks=C, backend=ref_backend))
+    host = make_engine(mk("padded"), GPipeConfig(
+        engine="host", balance=balance, chunks=C, backend="padded"))
+    comp = make_engine(mk("padded"), GPipeConfig(
+        engine="compiled", balance=balance, chunks=C, schedule="1f1b",
+        backend="padded"))
+    ph, lh = _assert_matches_reference(ref, host, plan, opt)
+    pc, lc = _assert_matches_reference(ref, comp, plan, opt)
+    assert lh == pytest.approx(lc, abs=1e-5), (kind, lh, lc)
+    _params_close(ph, pc, atol=1e-5)
+    assert isinstance(host.layout(plan.stacked().graph), BucketedGraphBatch)
+    assert host.describe()["agg_slot_share"] == comp.describe()["agg_slot_share"] < 1.0
 
 
 @pytest.mark.slow

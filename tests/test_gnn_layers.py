@@ -159,13 +159,70 @@ def test_gat_pallas_bucketed_matches_padded(skewed_mini):
 
 
 def test_padded_backend_ignores_bucket_wrapper(skewed_mini):
-    """BucketedGraphBatch delegates to its base: the padded/dense backends
-    see the wrapper as the plain padded batch (layout-blind plumbing)."""
+    """BucketedGraphBatch delegates to its base: the padded GAT and the
+    dense GCN see the wrapper as the plain padded batch (layout-blind
+    plumbing). The padded GCN reads the buckets (next test)."""
     g, b = skewed_mini
-    p = L.init_gcn(jax.random.PRNGKey(1), g.num_features, 8)
-    out_g = L.gcn_layer(p, g, g.features, backend="padded")
-    out_b = L.gcn_layer(p, b, g.features, backend="padded")
+    p = L.init_gat(jax.random.PRNGKey(1), g.num_features, 8, heads=2)
+    out_g = L.gat_layer(p, g, g.features, backend="padded")
+    out_b = L.gat_layer(p, b, g.features, backend="padded")
     assert jnp.array_equal(out_g, out_b)
+    p = L.init_gcn(jax.random.PRNGKey(1), g.num_features, 8)
+    out_g = L.gcn_layer(p, g, g.features, backend="dense")
+    out_b = L.gcn_layer(p, b, g.features, backend="dense")
+    assert jnp.array_equal(out_g, out_b)
+
+
+def capped_powerlaw():
+    """A power-law chunk capped at 32 neighbours (33-wide rows, some full),
+    cut by ``subgraph`` so rows have holes in the mask, and padded with
+    isolated rows that hold no slot at all."""
+    from repro.graphs import open_streamed
+    from repro.graphs.data import pad_graph, subgraph
+
+    ds = open_streamed("powerlaw-64k", num_nodes=4096, block_size=512)
+    g = ds.chunk_batch(0, 4096, max_degree=32)
+    g = subgraph(g, np.flatnonzero(np.arange(g.num_nodes) % 97 != 1))
+    g = pad_graph(g, g.num_nodes + 6, g.max_degree)
+    msk = np.asarray(g.mask)
+    assert g.max_degree == 33 and msk.all(axis=1).any()
+    assert (~msk.any(axis=1)).sum() == 6
+    assert ((~msk[:, :-1]) & msk[:, 1:]).any()  # a hole before a live slot
+    return g
+
+
+@pytest.fixture(scope="module", params=["capped-powerlaw", "skewed-mini"])
+def gcn_graph(request):
+    from repro.graphs import degree_bucketed_layout
+
+    g = capped_powerlaw() if request.param == "capped-powerlaw" else load_dataset("skewed-mini")
+    return g, degree_bucketed_layout(g)
+
+
+def test_gcn_padded_bucketed_matches_padded(gcn_graph):
+    """The padded GCN over the degree-bucketed wrapper computes the plain
+    padded gather's output and its gradients with respect to ``w``, ``b``
+    and ``h`` to float32 rounding: only padding slots, which add zero, are
+    left out."""
+    g, b = gcn_graph
+    p = L.init_gcn(jax.random.PRNGKey(3), g.num_features, 16)
+    ct = jax.random.normal(jax.random.PRNGKey(4), (g.num_nodes, 16))
+
+    def loss(p, h, graph):
+        return jnp.sum(L.gcn_layer(p, graph, h, backend="padded") * ct)
+
+    with jax.default_matmul_precision("highest"):
+        out_g = L.gcn_layer(p, g, g.features, backend="padded")
+        out_b = L.gcn_layer(p, b, g.features, backend="padded")
+        grad_g = jax.grad(loss, argnums=(0, 1))(p, g.features, g)
+        grad_b = jax.grad(loss, argnums=(0, 1))(p, g.features, b)
+    scale = float(jnp.max(jnp.abs(out_g)))
+    np.testing.assert_allclose(out_b, out_g, rtol=1e-5, atol=1e-6 * scale)
+    for name, want, got in (("w", grad_g[0]["w"], grad_b[0]["w"]),
+                            ("b", grad_g[0]["b"], grad_b[0]["b"]),
+                            ("h", grad_g[1], grad_b[1])):
+        scale = float(jnp.max(jnp.abs(want)))
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6 * scale, err_msg=name)
 
 
 def test_bucketed_layer_forced_kernel_matches_oracle(monkeypatch, skewed_mini):

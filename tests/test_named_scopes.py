@@ -206,3 +206,65 @@ def test_the_compile_cache_keeps_each_programs_own_names(tmp_path, monkeypatch):
         compilation_cache.reset_cache()
     assert "pipe.a" in texts[0] and "pipe.b" in texts[1]
     assert len([n for n in os.listdir(tmp_path) if n.startswith("jit_f")]) == 2
+
+
+_TYPED = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]*)\]")
+_SCATTER = re.compile(r" scatter\(([^)]*)\).*index_vector_dim=(\d+)")
+
+
+def _scatter_rows(text):
+    """Update rows of every ``scatter`` in compiled HLO text: the indices'
+    element count over the index vector's width."""
+    shapes, rows = {}, []
+    for line in text.splitlines():
+        m = _TYPED.match(line)
+        if m:
+            shapes[m.group(1)] = [int(d) for d in m.group(2).split(",") if d]
+    for line in text.splitlines():
+        m = _SCATTER.search(line)
+        if m:
+            idx = shapes[m.group(1).split(",")[1].strip().lstrip("%")]
+            ivd = int(m.group(2))
+            n = 1
+            for d in idx:
+                n *= d
+            rows.append(n // idx[ivd] if ivd < len(idx) else n)
+    return rows
+
+
+def test_bucketed_gcn_gradient_scatters_only_real_slots():
+    """The padded GCN's gradient over the degree-bucketed layout scatters
+    one row per bucket slot and one per node (the row permutation), fewer
+    than the padded layout's n * max_deg; every gather and scatter of the
+    aggregation sits under ``gnn.agg``."""
+    import jax.numpy as jnp
+
+    from repro.graphs import degree_bucketed_layout, open_streamed
+    from repro.graphs.partition import layout_slots
+    from repro.models.gnn import layers as L
+
+    ds = open_streamed("powerlaw-64k", num_nodes=4096, block_size=512)
+    g = ds.chunk_batch(0, 4096, max_degree=32)
+    b = degree_bucketed_layout(g)
+    p = L.init_gcn(jax.random.PRNGKey(0), g.num_features, 16)
+
+    def loss(p, h, graph):
+        return jnp.sum(L.gcn_layer(p, graph, h) ** 2)
+
+    def compiled(graph):
+        return jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            p, g.features, graph).compile().as_text()
+
+    n, width = g.neighbors.shape
+    assert sum(_scatter_rows(compiled(g))) == n * width
+    text = compiled(b)
+    rows = _scatter_rows(text)
+    assert len(rows) == len(b.buckets) + 1, rows
+    assert sum(rows) <= layout_slots(b) + n < n * width
+    assert layout_slots(b) < 0.6 * n * width
+    comps, _ = _parse(text)
+    names = _names(comps)
+    agg = [i["name"] for insts in comps.values() for i in insts
+           if i["op"] in ("gather", "scatter")]
+    assert agg and all("gnn.agg" in _bases(names[a]) for a in agg), [
+        (a, names[a]) for a in agg]
